@@ -15,13 +15,6 @@ from __future__ import annotations
 
 import numpy as np
 
-# Honor an inherited JAX_PLATFORMS before any backend initializes: this
-# module is the first thing the embedded interpreter (native/capi.c)
-# imports, so the override lands before any jax computation runs.
-from .platform import apply_env_platforms
-
-apply_env_platforms()
-
 _DT = {"s": np.float32, "d": np.float64,
        "c": np.complex64, "z": np.complex128}
 _RDT = {"s": np.float32, "d": np.float64,

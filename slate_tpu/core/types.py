@@ -248,7 +248,7 @@ class Options:
     # axis (parallel/panel.py — the hand-scheduled counterpart of the
     # GSPMD-inferred panel; reference Tile_getrf.hh:209-270)
     lu_dist_panel: bool = False
-    # Round-6 fast paths (PERF.md "Round 6"). lu_pivot_fusion: fold the
+    # Round-6 fast paths (PERF_HISTORY.md "Round 6"). lu_pivot_fusion: fold the
     # per-level row permutation into the trailing-update gemm READS
     # (gather-as-you-read + deferred left swaps) instead of
     # materializing a full-width permuted copy per level — the
@@ -278,7 +278,7 @@ class Options:
     # flops in sequential full-matrix matvecs); "two_stage" = he2hb
     # band reduction (all-gemm) + hb2td bulge chase on O(n·nb) data
     # (the reference's he2hb+hb2st split, src/he2hb.cc + src/hb2st.cc);
-    # "auto" picks per backend/size (see eig._heev_td and PERF.md)
+    # "auto" picks per backend/size (see eig._heev_td and PERF_HISTORY.md)
     eig_stage1: str = "auto"
     method_svd: MethodSVD = MethodSVD.Auto
     # printing (reference enums.hh:477-487)

@@ -2,11 +2,14 @@
 
 Reference analog: the scalapack_api/ + lapack_api/ interchange layers and
 BaseMatrix's layout-conversion machinery. The shared library is built on
-first use with the repo's Makefile (g++ -fopenmp); if no compiler is
-available, every entry point falls back to an equivalent numpy path so
-the framework stays importable (reference behavior: the APIs are optional
-CMake components, CMakeLists.txt:56). The fallback is LOGGED once
-(logging.warning) so a perf-relevant degradation can't pass silently.
+first use with the repo's Makefile (g++ -fopenmp), under a file name
+keyed on a hash of the committed sources — a library built from any
+other sources (an older tree, a copied checkout) is never loaded. If no
+compiler is available, every entry point falls back to an equivalent
+numpy path so the framework stays importable (reference behavior: the
+APIs are optional CMake components, CMakeLists.txt:56). The fallback is
+LOGGED once (logging.warning) so a perf-relevant degradation can't pass
+silently.
 
 Round 5: all packers are dtype-generic — f32/f64/c64/c128 dispatch into
 the element-size-templated native kernels (st_*_e symbols), matching the
@@ -17,6 +20,7 @@ reference's four-precision scalapack_api surface
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -33,7 +37,7 @@ _TRIED = False
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
-_SO = os.path.join(_NATIVE_DIR, "libslate_tpu_host.so")
+_SOURCES = ("layout.cc", "steqr.cc", "Makefile")
 
 _I64 = ctypes.c_int64
 _PV = ctypes.c_void_p
@@ -49,12 +53,27 @@ _NATIVE_DTYPES = {
 }
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """native/libslate_tpu_host.<hash of _SOURCES>.so"""
+    h = hashlib.sha256()
+    for f in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, f), "rb") as fh:
+            h.update(fh.read())
+    return os.path.join(_NATIVE_DIR,
+                        f"libslate_tpu_host.{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    """Build ``so`` from the sources; the rename makes concurrent
+    builders (test workers) each publish a whole file."""
+    tmp = f"{so}.{os.getpid()}.tmp"
+    name = os.path.basename(tmp)
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True)
-        return os.path.exists(_SO)
-    except Exception:
+        subprocess.run(["make", "-C", _NATIVE_DIR, name,
+                        f"TARGET={name}"], check=True, capture_output=True)
+        os.replace(tmp, so)
+        return True
+    except (OSError, subprocess.CalledProcessError):
         return False
 
 
@@ -66,7 +85,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
-        if not os.path.exists(_SO) and not _build():
+        try:
+            so = _so_path()
+        except OSError:
+            so = None  # sources absent: an installed package, no build
+        if so is None or (not os.path.exists(so) and not _build(so)):
             _LOG.warning(
                 "native layout library unavailable (no compiler or build "
                 "failed); interop packers fall back to numpy — correct "
@@ -102,7 +125,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
         def _load():
             try:
-                lib = ctypes.CDLL(_SO)
+                lib = ctypes.CDLL(so)
             except OSError:
                 return None
             for name, argtypes in symbols:
@@ -113,15 +136,12 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 fn.restype = _I64
             return lib
 
-        lib = _load()
-        if lib is None and _build():
-            lib = _load()
-        _LIB = lib
+        _LIB = _load()
         if _LIB is None:
             _LOG.warning(
-                "native layout library failed to load (stale or "
-                "unlinkable %s); interop packers fall back to numpy — "
-                "correct but slower", _SO)
+                "native layout library failed to load (unlinkable %s); "
+                "interop packers fall back to numpy — correct but "
+                "slower", so)
         return _LIB
 
 

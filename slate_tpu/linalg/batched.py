@@ -26,7 +26,7 @@ differently at different batch shapes (a single complex multiply
 reproduces it), so c64 lanes agree across buckets only to a few ulp
 on the CPU backend — exact within a bucket, and not a TPU property
 (complex matmuls lower to real MXU pairs there). All pinned in
-tests/test_batched.py; PERF.md Round 10 documents the caveat.
+tests/test_batched.py; PERF_HISTORY.md Round 10 documents the caveat.
 
 Per-item ``info`` vectors follow the LAPACK convention (0 = ok,
 k > 0 = first failing column/minor); one singular item flags itself

@@ -142,7 +142,7 @@ def _potrf_rec(a: jax.Array, nb: int, prec, lookahead: int = 1):
 # loop's single batched-leaf inverse per panel wins on latency; above
 # it the round-5 loop's trailing-block re-traffic (herk_lower_rec's
 # per-level concatenation copies) lost to the recursion's O(n² log nt)
-# touch pattern (perf_traces/SUMMARY.md). Round 6: the crossover only
+# touch pattern (PERF_HISTORY.md round 5). Round 6: the crossover only
 # gates the RECURSION's base case (the legacy dispatch,
 # Options.factor_iter_large=False) — the default dispatch runs the
 # iterative loop at ALL sizes with nt ≤ _ITER_MAX_NT, because its
@@ -188,7 +188,7 @@ def _potrf_iter(a: jax.Array, nb: int, prec, lookahead: int = 1):
     of step k+1 is factored IMMEDIATELY from it, and only then are the
     remainder slabs written. The step-(k+1) tile factor (the serial
     ~n·sqrt/divide chain that is potrf's single-chip latency floor,
-    PERF.md) therefore has NO data edge to the remainder slabs of step
+    PERF_HISTORY.md) therefore has NO data edge to the remainder slabs of step
     k — the scheduler is free to interleave the panel's VPU/scalar
     chain with the remainder's MXU gemms (asserted structurally in
     tests/test_lookahead.py, and on the scheduled HLO where the

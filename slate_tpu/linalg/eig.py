@@ -9,7 +9,7 @@ eigensolvers), src/unmtr_he2hb.cc, src/unmtr_hb2st.cc (back-transforms).
 TPU-native design (SURVEY §7.7), round-3 state:
 - Stage 1, two strategies (Options.eig_stage1): ``he2td`` — direct
   blocked tridiagonalization, O(1)-HLO fori_loops, back-transform is
-  pure stacked gemms (the single-chip default, measured in PERF.md);
+  pure stacked gemms (the single-chip default, measured in PERF_HISTORY.md);
   ``two_stage`` — he2hb band reduction (all-gemm, O(log nt) fixed-shape
   level programs) + hb2td bulge chase (O(n·nb) data touched per sweep,
   the reference's he2hb + hb2st split, src/he2hb.cc + src/hb2st.cc).
@@ -693,7 +693,8 @@ def _heev_td(A: TiledMatrix, opts: Options, want_vectors: bool,
         # he2td: the back-transform is pure stacked gemms and stage 1
         # costs one reduction; two_stage buys its O(n·nb)-data stage 2
         # at the price of the bulge chase's sequential window chain —
-        # measured slower end-to-end on one chip up to n=8192 (PERF.md),
+        # measured slower end-to-end on one chip up to n=8192
+        # (PERF_HISTORY.md),
         # so auto = he2td until multi-chip stage-1 sharding tips it
         stage1 = "he2td"
     two_stage = stage1 == "two_stage" and A.shape[0] >= 3 * nb

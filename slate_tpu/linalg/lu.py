@@ -404,16 +404,11 @@ def getrf(A: TiledMatrix, opts: Options = DEFAULT_OPTIONS
     m, n = A.shape
     a = _canonical(A)
     a = _pad_identity_diag(a, m, n)
-    from ..parallel import panel as panel_mod
-    # on pre-0.6 jax the dist-panel recursion mis-partitions under GSPMD
-    # (old shard_map rep semantics + partitioner bugs — see panel.py);
-    # honor the option only where the composition is sound
-    dist_panel = opts.lu_dist_panel and panel_mod.DRIVER_COMPOSABLE
     with blocked.distribute_on(A.grid):
         lu, perm, info = _getrf_blocked(
             a, A.nb, min(A.mt, A.nt),
             prec=opts.update_precision,
-            dist_panel=dist_panel,
+            dist_panel=opts.lu_dist_panel,
             threshold=opts.pivot_threshold,
             fused=opts.lu_pivot_fusion,
             iter_large=opts.factor_iter_large,

@@ -175,7 +175,7 @@ def _secular_kernel_body(dhi, dlo, z2hi, z2lo, rho_hi, rho_lo,
     Mirrors _secular_roots stage for stage (pole choice by midpoint
     sign, 55 bisections, bracket-safeguarded Newton, near-pole fixed
     point) in double-single f32 (ops/doublefloat.py) — the TPU-native
-    replacement of the host numpy sweep, which PERF.md measured at
+    replacement of the host numpy sweep, which PERF_HISTORY.md measured at
     13.5 s of a 19 s n=4096 solve. Reference: src/stedc_secular.cc
     (grid-parallel dlaed4 calls); here every root is one lane of a
     vectorized VPU program instead of one LAPACK call."""
@@ -308,7 +308,7 @@ def _secular_sharded_fn(mesh, kp: int, chunk: int):
     (src/stedc_secular.cc:1-80). No collectives are needed inside the
     sweep; GSPMD inserts only the initial broadcast of the O(k) pole
     vectors."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..core.grid import COL_AXIS, ROW_AXIS
@@ -322,7 +322,7 @@ def _secular_sharded_fn(mesh, kp: int, chunk: int):
         return _secular_kernel_body(dh, dl, zh, zl, rh, rl, wh, wl, jj,
                                     nl, chunk_l)
 
-    fn = shard_map(body, mesh,
+    fn = shard_map(body, mesh=mesh,
                    in_specs=(spec_0, spec_0, spec_0, spec_0, spec_0,
                              spec_0, spec_r, spec_r, spec_r, spec_r),
                    out_specs=(spec_r, spec_r, spec_r))

@@ -19,7 +19,7 @@ tools ingest:
   /trace.json).
 * :mod:`.merge`      — aligns host spans with ``jax.profiler`` device
   traces via the ``potrf_l{k}_*``/``geqrf_l{k}_*`` named scopes and
-  computes the measured lookahead-overlap metric (PERF.md round 7's
+  computes the measured lookahead-overlap metric (PERF_HISTORY.md round 7's
   modeled number, measured); round 12 adds the multi-process trace
   combine (``combine_process_traces``).
 * :mod:`.slo`        — declarative serving objectives evaluated over

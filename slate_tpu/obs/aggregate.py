@@ -13,7 +13,7 @@ Chrome trace; this module merges them —
   extremes, the merged mean is recomputed, and the merged p50/p99 are
   the count-weighted mean of the per-process quantiles (an
   approximation — exact fleet quantiles need the raw samples, which
-  snapshots deliberately do not ship; documented in PERF.md Round 12);
+  snapshots deliberately do not ship; documented in PERF_HISTORY.md Round 12);
   the worst-valued exemplar survives;
 * **gauges labeled per host** (a fleet has one resident_bytes per
   chip, not one sum; summable gauges are ALSO aggregated under

@@ -45,7 +45,7 @@ config (round 10): those collectives are multiplied by the trip count,
 because they execute once per iteration. A while without a trip count
 (data-dependent loops) falls back to counted-once — so looped programs
 report a LOWER bound exactly when XLA itself cannot bound the loop
-(documented in PERF.md Rounds 9–10; nested whiles multiply by the
+(documented in PERF_HISTORY.md Rounds 9–10; nested whiles multiply by the
 innermost counted loop only, again a lower bound).
 """
 

@@ -9,7 +9,7 @@ labels — the per-level ``potrf_l{k}_tile/_panel/_trail_next/_trail_rest
 things with them:
 
 * :func:`lookahead_overlap` — the MEASURED version of the number
-  PERF.md round 7 only models: for each level k, how much of the
+  PERF_HISTORY.md round 7 only models: for each level k, how much of the
   level-(k+1) lookahead panel's device time runs CONCURRENTLY with the
   level-k remainder ("trail_rest") gemms. ``overlap_fraction`` = hidden
   panel seconds / total lookahead-panel seconds: 1.0 means the panel

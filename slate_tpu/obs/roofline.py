@@ -13,7 +13,7 @@ The machine model is explicit, never guessed: pass a
 ``SLATE_TPU_HBM_GBPS`` in the environment (per-chip numbers; for the
 BASELINE pod run the ICI roof matters too — ``ici_gbps``). Without one,
 rows still carry intensity and measured rates; the bound/attainable
-columns are ``None`` (an honest roofline needs a measured roof, PERF.md
+columns are ``None`` (an honest roofline needs a measured roof, PERF_HISTORY.md
 Round 9).
 """
 

@@ -78,6 +78,14 @@ def distribute_on(grid):
         _GRID_CTX.reset(tok)
 
 
+def _single_device() -> bool:
+    """No multi-device grid is installed: only then may a driver call a
+    Pallas kernel. GSPMD partitions a sharded program and cannot
+    partition a Mosaic call ("Mosaic kernels cannot be automatically
+    partitioned"), so the sharded drivers take the XLA bases."""
+    return _GRID_CTX.get() is None
+
+
 def current_grid():
     """The grid installed by distribute_on (None outside a context) —
     the public accessor; callers must not read _GRID_CTX directly."""
@@ -357,13 +365,13 @@ def herk_lower_rec(c: Array, a: Array, b: Optional[Array] = None,
     is an OPT-IN alternative for the pure-herk case
     (SLATE_TPU_PALLAS_HERK=1, single device, divisible shapes): round-3
     A/B measurement showed it HBM-bound on tile re-reads and no faster
-    than this recursion end-to-end (PERF.md), so the jnp path is the
+    than this recursion end-to-end (PERF_HISTORY.md), so the jnp path is the
     default. Multi-device grids always use the recursion (GSPMD cannot
     partition a pallas_call, and rebalance() constraints live here)."""
     if b is None:
         from . import pallas_ops
         blk = pallas_ops.default_block(a.shape[1])
-        if _GRID_CTX.get() is None and pallas_ops.herk_eligible(
+        if _single_device() and pallas_ops.herk_eligible(
                 c.shape[0], a.shape[1], c.dtype, blk):
             # kernel runs HIGHEST regardless of prec (see pallas_ops —
             # it is HBM-bound, so the pass count doesn't matter)
@@ -410,7 +418,7 @@ def herk_trailing_inplace(a: Array, pan: Array, k1: int, nb: int,
     through herk_lower_rec, whose 2×2 recursion concatenates full
     copies of the trailing block per level — the measured
     O(n²·log nt)-per-step re-traffic that set the round-5 n=2048
-    crossover (perf_traces/SUMMARY.md). Here each trailing column slab
+    crossover (PERF_HISTORY.md round 5). Here each trailing column slab
     j gets ONE gemm  pan[j0−k1:]·pan[j0−k1:j1−k1]ᴴ  and ONE
     dynamic_update_slice write of the (s−j0)×nb slab — the lower
     trapezoid is touched exactly once per step and the flop count is
@@ -499,12 +507,12 @@ def chol_tile_blocked(a: Array, ib: int = 64) -> Array:
     on non-SPD like lax.linalg.cholesky (sqrt of negative)."""
     b = a.shape[0]
     from . import pallas_ops
-    if pallas_ops.chol_eligible(b, a.dtype):
+    if _single_device() and pallas_ops.chol_eligible(b, a.dtype):
         # round 5: the whole tile factor as ONE Mosaic kernel — the
         # fori_loop path below pays ~230 µs per ib-step in per-op
         # dispatch latency (64 sequential trtri matvecs, each its own
         # XLA op); in-kernel the same chain is pipeline-latency only
-        # (measured: perf_traces/SUMMARY.md, tools/potrf_ab.py)
+        # (measured: PERF_HISTORY.md round 5, tools/potrf_ab.py)
         return pallas_ops.chol_tile(a)
     if b % ib or b <= ib:
         if a.dtype in (jnp.bfloat16, jnp.float16):
@@ -651,11 +659,13 @@ def panel_getrf(a: Array, ib: int = PANEL_IB,
         # XLA base was tried in round 3 and OOM-killed the compiler at
         # n=16384 panel heights, the fori base is the fallback).
         from . import pallas_ops
-        if pallas_ops.lu_panel_eligible(hh, w, a.dtype):
+        if _single_device() and pallas_ops.lu_panel_eligible(hh, w,
+                                                             a.dtype):
             return pallas_ops.lu_panel_base(a)
         return _panel_getrf_base(a)
     from . import pallas_ops
-    if pallas_ops.lu_panel_eligible(hh, w, a.dtype):
+    if _single_device() and pallas_ops.lu_panel_eligible(hh, w,
+                                                         a.dtype):
         # round 7 (deeper-unrolled bases): a WIDE base (w ≤ 128) runs
         # as ONE kernel invocation instead of recursing into 32-wide
         # bases with XLA trsm/gemm aggregation between them — the
@@ -884,11 +894,13 @@ def panel_geqrf(a: Array, ib: int = PANEL_IB,
         # column (pallas_ops._qr_panel_kernel; same rationale as the
         # LU panel base above).
         from . import pallas_ops
-        if pallas_ops.qr_panel_eligible(hh, w, a.dtype):
+        if _single_device() and pallas_ops.qr_panel_eligible(hh, w,
+                                                             a.dtype):
             return pallas_ops.qr_panel_base(a)
         return _panel_geqrf_base(a)
     from . import pallas_ops
-    if pallas_ops.qr_panel_wide_eligible(hh, w, a.dtype):
+    if _single_device() and pallas_ops.qr_panel_wide_eligible(hh, w,
+                                                              a.dtype):
         # round 7 (deeper-unrolled bases): a wide base runs as ONE
         # micro-blocked kernel — per-column Householder updates
         # restricted to 32-lane micro-blocks, compact-WY MXU updates

@@ -79,11 +79,7 @@ def herk_eligible(n: int, k: int, dtype, block: int) -> bool:
     shapes, at least 2 tile rows (otherwise there is nothing to save)."""
     if os.environ.get("SLATE_TPU_PALLAS_HERK") != "1":
         return False  # opt-in: measured no win over the jnp recursion
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return False
-    if backend != "tpu":
+    if jax.default_backend() != "tpu":
         return False
     if dtype not in (jnp.float32.dtype, jnp.bfloat16.dtype,
                      np.dtype("float32"), np.dtype("bfloat16")):
@@ -174,7 +170,7 @@ def herk_lower_update(c: jax.Array, a: jax.Array,
 # In-VMEM blocked tile Cholesky (round 5)
 # ---------------------------------------------------------------------------
 #
-# Round-5 on-chip profiling (perf_traces/SUMMARY.md) showed the tile
+# Round-5 on-chip profiling (PERF_HISTORY.md round 5) showed the tile
 # Cholesky is the single-chip potrf floor: chol_tile_blocked's
 # fori_loop pays ~230 us per ib-step, almost all of it the 64
 # SEQUENTIAL (1,ib)@(ib,ib) matvecs of the unrolled trtri — each a
@@ -301,17 +297,16 @@ def _chol_tile_kernel(a_ref, out_ref):
 def _panel_gate(env_var: str, dtype, shape_ok: bool) -> bool:
     """Shared eligibility gate for the in-VMEM factor kernels: env
     kill switch, real f32 only, caller's shape predicate, and (last,
-    so CPU-host tests exercise the rest) a real-TPU backend check."""
+    so CPU-host tests exercise the rest) a real-TPU backend check. A
+    backend that fails to initialise raises here: it is an error, not
+    "no TPU"."""
     if os.environ.get(env_var) == "0":
         return False
     if dtype not in (jnp.float32.dtype, np.dtype("float32")):
         return False
     if not shape_ok:
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def chol_eligible(b: int, dtype) -> bool:
@@ -372,6 +367,12 @@ def chol_tile(a: jax.Array, *, interpret: bool = False) -> jax.Array:
 # ~2.5 s and runs with headroom, so the budget is 8192·32 cells;
 # taller bases fall back to the XLA fori base.
 _PANEL_MAX_CELLS = 8192 * 32
+# The 16 MiB is Mosaic's default scoped limit, not the chip's: v5e has
+# 128 MiB of VMEM. Inside a geqrf program (gels 8192×2048, nb=512,
+# AOT-compiled for v5e in PR 21) a (7968, 32) QR base asked for
+# 16.29 MiB, so the panel kernels raise their limit to 64 MiB — four
+# times what a budget-sized base was seen to take.
+_PANEL_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2 ** 20)
 
 
 def _lu_panel_kernel(a_ref, lu_ref, perm_ref, info_ref):
@@ -454,6 +455,7 @@ def lu_panel_base(a: jax.Array, *, interpret: bool = False):
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
+        compiler_params=_PANEL_PARAMS,
         interpret=interpret,
     )(a)
     return lu, perm[:, 0], info[0, 0]
@@ -674,6 +676,7 @@ def qr_panel_base_wide(a: jax.Array, *, interpret: bool = False):
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM)),
+        compiler_params=_PANEL_PARAMS,
         interpret=interpret,
     )(a)
     return vr, taus[:, 0]
@@ -692,6 +695,7 @@ def qr_panel_base(a: jax.Array, *, interpret: bool = False):
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM)),
+        compiler_params=_PANEL_PARAMS,
         interpret=interpret,
     )(a)
     return vr, taus[:, 0]

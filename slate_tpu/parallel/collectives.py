@@ -57,15 +57,6 @@ def reduce_max(x, axis: str):
     return lax.pmax(x, axis)
 
 
-def _axis_size(axis: str) -> int:
-    """Static mesh-axis size inside shard_map; lax.axis_size is absent
-    on pre-0.6 jax, where core.axis_frame(name) returns the size."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    from jax import core
-    return int(core.axis_frame(axis))
-
-
 def maxloc(values, axis: str):
     """Global (max, argmax-shard, argmax-local) along a mesh axis.
 
@@ -91,7 +82,7 @@ def ring_shift(x, axis: str, shift: int = 1):
     block for ring pipelines (the reference's step-doubling tileSend/
     tileRecv exchanges, internal_ttqrt.cc:91-127, are log₂ rounds of
     this with strides 1,2,4,…)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 
@@ -104,7 +95,7 @@ def tree_reduce_pairwise(x, combine, axis: str):
     partner = me XOR 2^r and combine(lo, hi). All members end with the
     root's result (butterfly/allreduce shape, like the reference's
     reduce-then-bcast)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     me = lax.axis_index(axis)
     r = 1
     while r < n:

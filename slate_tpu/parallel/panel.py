@@ -11,19 +11,17 @@ psum), two masked-psum row broadcasts (the cross-shard row swap), and a
 purely local rank-1 update. This is the explicit counterpart of the
 GSPMD-inferred panel (ops/blocked.panel_getrf); `getrf` routes here
 when ``Options.lu_dist_panel`` is set and a multi-device grid is
-active. Measured comparison against the GSPMD panel: PERF.md.
+active. Measured comparison against the GSPMD panel: PERF_HISTORY.md.
 
 Round-6 dispatch note: the default getrf now runs the PIVOT-FUSED
 iterative outer loop (linalg/lu.py::_getrf_iter — permutation folded
 into the trailing-update reads, deferred left swaps). The dist-panel
 route keeps the 2×2 width recursion as its driver: the explicit
 shard_map panel is a per-PANEL replacement and composes with either
-outer loop, but on pre-0.6 jax (DRIVER_COMPOSABLE=False) the old
-shard_map mis-lowers inside any GSPMD-partitioned driver, so the
-conservative recursion pairing is kept until the new-style shard_map
-is the floor. The fused loop's deferred left swaps would subsume the
-reference's cross-rank pivot-row exchange the same way (the suffix
-gathers become collective-permutes on a mesh).
+outer loop; the recursion pairing is the one the tests pin. The
+fused loop's deferred left swaps would subsume the reference's
+cross-rank pivot-row exchange the same way (the suffix gathers become
+collective-permutes on a mesh).
 
 Round-7 notes. (1) LOOKAHEAD: the default outer loops now pipeline —
 panel k+1 is factored between the next-panel slab and the remainder
@@ -55,20 +53,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-    # new-style shard_map (jax >= 0.6): sound replication tracking; the
-    # explicit panel may be composed into the GSPMD-partitioned driver
-    DRIVER_COMPOSABLE = True
-except ImportError:  # pre-0.6 jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
-    # old shard_map: check_rep=True rejects the fori_loop carry (rep
-    # mismatch) and check_rep=False silently mis-lowers the P() outputs
-    # (psum over the unmentioned q axis) when NESTED inside the
-    # GSPMD-partitioned getrf driver — standalone calls are fine, so
-    # only the driver route is gated (linalg/lu.py falls back to the
-    # GSPMD panel there)
-    DRIVER_COMPOSABLE = False
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.grid import ROW_AXIS
@@ -140,23 +125,17 @@ def dist_panel_getrf(a: jax.Array, grid) -> Tuple[jax.Array, jax.Array,
             0, w, col_step, (al, perm0, jnp.zeros((), jnp.int32)))
         return al, perm, info
 
-    try:
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=P(ROW_AXIS, None),
-                       out_specs=(P(ROW_AXIS, None), P(), P()),
-                       check_vma=False)
-    except TypeError:  # pre-0.6 jax spells the kwarg check_rep
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=P(ROW_AXIS, None),
-                       out_specs=(P(ROW_AXIS, None), P(), P()),
-                       check_rep=False)
+    fn = shard_map(body, mesh=mesh,
+                   in_specs=P(ROW_AXIS, None),
+                   out_specs=(P(ROW_AXIS, None), P(), P()),
+                   check_vma=False)
     a = lax.with_sharding_constraint(
         a, NamedSharding(mesh, P(ROW_AXIS, None)))
     # cost telemetry (round 9): per-shape AOT analysis of the compiled
     # panel (the per-column maxloc pmax/pmin/psum + two masked-psum row
     # broadcasts show up in the collective census; note the fori_loop
     # body is counted once per INSTRUCTION, so the census is a per-
-    # column lower bound — PERF.md Round 9), credited to the process
+    # column lower bound — PERF_HISTORY.md Round 9), credited to the process
     # bytes ledger on every eager call (obs/costs.py).
     return obs_costs.call_analyzed(
         fn, (a,), label=f"parallel.panel_getrf[p{p}]")

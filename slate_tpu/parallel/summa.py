@@ -22,10 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.grid import COL_AXIS, ROW_AXIS, ProcessGrid
 from ..core.tiled_matrix import TiledMatrix, from_dense

@@ -884,11 +884,12 @@ class Session:
 
             def fwd(x):
                 B = self._wrap_rhs(entry, np.ascontiguousarray(x))
-                key = self._aot_key(entry, payload, B)
+                Bw = _at_tile_width(B)
+                key = self._aot_key(entry, payload, Bw)
                 exe = self._compiled.get(key)
                 if exe is None and entry.grid is not None:
                     exe = self._aot_compile("solve", entry, handle,
-                                            solve_fn, (payload, B),
+                                            solve_fn, (payload, Bw),
                                             key=key)
                     self._compiled_put(key, exe)
                     self.metrics.inc("aot_compiles")
@@ -896,8 +897,8 @@ class Session:
                     self._compiled.move_to_end(key)
                     self._credit_program(key, "numerics.condest",
                                          tenant=tenant, handle=handle)
-                    return host(exe(payload, B))
-                return host(solve_fn(payload, B))
+                    return host(_at_width_of(exe(payload, Bw), B))
+                return host(_at_width_of(solve_fn(payload, Bw), B))
 
         if op == "chol":
             # A⁻ᴴ = A⁻¹ (HPD resident) — the pocondest convention
@@ -2185,7 +2186,7 @@ class Session:
             if tenant is not None:
                 kw["tenant"] = tenant
             X = self.solve_matrix(handle, B, **kw)
-            x = (X.to_numpy() if isinstance(X, TiledMatrix)
+            x = (_host_crop(X) if isinstance(X, TiledMatrix)
                  else np.asarray(X)[: entry.n])
             return x[:, 0] if vector else x
 
@@ -2844,10 +2845,16 @@ class Session:
         if entry.op in ("band_lu", "band_chol"):
             return jax.numpy.asarray(b2)
         nb = entry.A.nb
-        # mesh operators get a mesh-placed right-hand side (grid=None
-        # is the single-device no-op): the solve program then consumes
-        # sharded inputs end to end instead of all-gathering at entry
-        return from_dense(b2, nb=nb, grid=entry.grid)
+        # padded to whole tiles here, on the host: padding on the device
+        # compiles a program for every new request width. Mesh operators
+        # get a mesh-placed right-hand side (grid=None is the
+        # single-device no-op): the solve program then consumes sharded
+        # inputs end to end instead of all-gathering at entry
+        m, k = b2.shape
+        padded = np.zeros((-(-m // nb) * nb, -(-k // nb) * nb), b2.dtype)
+        padded[:m, :k] = b2
+        return dataclasses.replace(
+            from_dense(padded, nb=nb, grid=entry.grid), m=m, n=k)
 
     def _dispatch(self, entry: _Operator, res: _Resident, B,
                   handle: Hashable = None,
@@ -2869,11 +2876,12 @@ class Session:
                                           served_cols=served_cols,
                                           tenant=tenant)
         fn = self._solve_fn(entry)
-        key = self._aot_key(entry, res.payload, B)
+        Bw = _at_tile_width(B)
+        key = self._aot_key(entry, res.payload, Bw)
         exe = self._compiled.get(key)
         if exe is None and entry.grid is not None:
             exe = self._aot_compile("solve", entry, handle, fn,
-                                    (res.payload, B), key=key)
+                                    (res.payload, Bw), key=key)
             self._compiled_put(key, exe)
             self.metrics.inc("aot_compiles")
         if exe is not None:
@@ -2883,8 +2891,8 @@ class Session:
                   else (k - served_cols) / k)
             self._credit_program(key, "serve.solve", waste_fraction=wf,
                                  tenant=tenant, handle=handle)
-            return exe(res.payload, B)
-        return fn(res.payload, B)
+            return _at_width_of(exe(res.payload, Bw), B)
+        return _at_width_of(fn(res.payload, Bw), B)
 
     def _solve_fn(self, entry: _Operator):
         return self._jit_cached(
@@ -3621,6 +3629,7 @@ class Session:
                 jnp.zeros((npad, npad), dtype=dt))
         B = self._wrap_rhs(entry, np.zeros(
             (entry.m + int(update_k), nrhs), np.dtype(entry.A.dtype)))
+        B = _at_tile_width(B)
         skey = self._aot_key(entry, pay5, B)
         if skey not in self._compiled:
             fn = self._solve_fn(entry)
@@ -3946,12 +3955,13 @@ class Session:
                     # request-path compiles
                     self._condest_texe(entry, handle, res.payload, B)
                 return
-            key = self._aot_key(entry, res.payload, B)
+            Bw = _at_tile_width(B)
+            key = self._aot_key(entry, res.payload, Bw)
             if key not in self._compiled:
                 fn = self._solve_fn(entry)
                 self._compiled_put(
                     key, self._aot_compile("solve", entry, handle, fn,
-                                           (res.payload, B), key=key))
+                                           (res.payload, Bw), key=key))
                 self.metrics.inc("aot_compiles")
             if self.numerics is not None:
                 # round 16: precompile the numerics programs off the
@@ -4295,6 +4305,30 @@ def _make_probe_fn(op: str, opts: Options):
 
     probe.__name__ = f"serve_{op}_probe"
     return probe
+
+
+def _at_tile_width(B):
+    """B with its logical width raised to its tile-padded storage width
+    (the same data): every request width up to a tile then runs one
+    solve program, the one warmup compiled, since the logical width
+    is part of the program's key. The padded columns are zero."""
+    if not isinstance(B, TiledMatrix) or B.n == B.data.shape[1]:
+        return B
+    return dataclasses.replace(B, n=int(B.data.shape[1]))
+
+
+def _at_width_of(X, B):
+    """The solve's result back at B's logical width."""
+    if not isinstance(X, TiledMatrix) or X.n == B.n:
+        return X
+    return dataclasses.replace(X, n=B.n)
+
+
+def _host_crop(X: TiledMatrix) -> np.ndarray:
+    """X.to_numpy() cropped on the host: slicing on the device compiles
+    a program for every new request width."""
+    mm, nn = X.shape
+    return np.asarray(X.dense())[:mm, :nn]
 
 
 def _make_solve_fn(op: str, opts: Options):

@@ -54,7 +54,7 @@ def _assert_lane_matches(dtype, got, want):
     bucket program, complex lanes are exact too
     (test_bucket_padding_never_changes_bits). On TPU complex matmuls
     lower to real MXU pairs — this is a CPU-backend caveat, documented
-    in PERF.md Round 10."""
+    in PERF_HISTORY.md Round 10."""
     got, want = np.asarray(got), np.asarray(want)
     if np.issubdtype(dtype, np.complexfloating):
         eps = np.finfo(np.zeros(1, dtype).real.dtype).eps

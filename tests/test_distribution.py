@@ -357,8 +357,9 @@ def test_mesh_potrf_async_collective_start_done_interleaving(grid2x4):
     """The stronger TPU-shaped assertion: async collective start/done
     pairs with independent trailing-update compute scheduled BETWEEN
     start and done (true latency hiding). XLA:CPU lowers collectives
-    synchronously (zero *-start/done pairs — verified in PERF.md round
-    4), so this skips off-TPU and runs on a TPU-attached session."""
+    synchronously (zero *-start/done pairs — verified in
+    PERF_HISTORY.md round 4), so this skips off-TPU and runs on a
+    TPU-attached session."""
     hlo, _ = _scheduled_potrf_entry(grid2x4)
     starts = re.findall(r"%(\S*?(?:all-gather|all-reduce|"
                         r"collective-permute)-start\S*)\s*=", hlo)

@@ -145,7 +145,8 @@ def _ancestor_eqns(jaxpr, target_idx):
 def _pjit_indices(jaxpr, name):
     out = []
     for i, e in enumerate(jaxpr.eqns):
-        if e.primitive.name in ("pjit", "closed_call", "core_call"):
+        # the nested-jit call primitive: "jit" in JAX 0.9.0, "pjit" before
+        if e.primitive.name in ("jit", "pjit", "closed_call", "core_call"):
             if e.params.get("name") == name:
                 out.append(i)
     return out

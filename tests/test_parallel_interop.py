@@ -12,10 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import slate_tpu as st

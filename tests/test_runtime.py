@@ -331,6 +331,33 @@ def test_executor_warmup_aot():
     assert np.array_equal(x, sess2.solve(h2, b))
 
 
+@pytest.mark.parametrize("make", [_chol_handle, _lu_handle])
+def test_warmup_covers_every_request_width(make):
+    """One warmup at nrhs=1 serves every request width up to a tile
+    with nothing lowered: the solve runs at the tile-padded width, and
+    the right-hand side is padded and cropped on the host."""
+    import jax.monitoring
+
+    sess = Session()
+    h, a = make(sess)
+    sess.warmup(h)
+    lowered = []
+
+    def on(event, secs, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        for k in (1, 3, NB):
+            b = RNG.standard_normal((N, k))
+            x = sess.solve(h, b)
+            assert x.shape == (N, k)
+            assert np.abs(a @ x - b).max() < 1e-8
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert lowered == []
+
+
 def test_warmup_compiles_factor_program():
     """Round 7: warmup AOT-compiles the whole-factor program (the
     lookahead-pipeline driver) per operand shape, so refactor-on-miss

@@ -40,10 +40,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from slate_tpu.compat.platform import apply_env_platforms  # noqa: E402
-
-apply_env_platforms()
-
 
 def main(argv=None) -> int:
     from slate_tpu.tuning.search import (DEFAULT_OPS, run_search)

@@ -57,10 +57,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import numpy as np  # noqa: E402
 
-from slate_tpu.compat.platform import apply_env_platforms  # noqa: E402
-
-apply_env_platforms()
-
 RESID_TOL = 1e-3  # f32 working precision, n<=64 (|Ax-b|_inf / n|x|_inf)
 
 

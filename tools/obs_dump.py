@@ -60,9 +60,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from slate_tpu.compat.platform import (  # noqa: E402
-    apply_env_platforms, collective_timeout_flag_if_supported)
-
-apply_env_platforms()
+    collective_timeout_flag_if_supported)
 
 # On the CPU backend, run the smoke on an 8-way virtual-device mesh so
 # the MESH-driver cost telemetry (parallel.summa collective bytes —

@@ -25,10 +25,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from slate_tpu.compat.platform import apply_env_platforms  # noqa: E402
-
-apply_env_platforms()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
