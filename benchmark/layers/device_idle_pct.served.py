@@ -1,0 +1,10 @@
+"""Idle share of the device over the traced window of served requests:
+100 × (1 − union of device op intervals / window). Between batches the
+device waits for arrivals and for the host."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr is None or tr.window_s <= 0:
+        return None
+    return 100.0 * tr.idle_share
