@@ -211,16 +211,18 @@ def _potrf_iter(a: jax.Array, nb: int, prec, lookahead: int = 1):
         else:
             lkk, tinfo = ahead
             ahead = None
-        info = jnp.where((info == 0) & (tinfo > 0), k0 + tinfo,
-                         info).astype(jnp.int32)
-        a = dus(a, lkk, k0, k0)
+        with jax.named_scope(f"potrf_l{k}_store"):
+            info = jnp.where((info == 0) & (tinfo > 0), k0 + tinfo,
+                             info).astype(jnp.int32)
+            a = dus(a, lkk, k0, k0)
         if k1 >= s:
             continue
         with jax.named_scope(f"potrf_l{k}_panel"):
             inv = blocked.trtri_lower_batched(lkk)
             pan = blocked.mm(a[k1:, k0:k1], jnp.conj(inv).T, prec)
             pan = blocked.rebalance(pan)
-        a = dus(a, pan, k1, k0)
+        with jax.named_scope(f"potrf_l{k}_store"):
+            a = dus(a, pan, k1, k0)
         if lookahead >= 1 and k1 + nb <= s:
             # (a) the next-panel slab alone …
             with jax.named_scope(f"potrf_l{k}_trail_next"):
@@ -271,7 +273,8 @@ def _potrf_blocked(a: jax.Array, nb: int, nt: int, prec: str = "high",
         out, info = _potrf_iter(a, nb, prec=prec, lookahead=lookahead)
     else:
         out, info = _potrf_rec(a, nb, prec=prec, lookahead=lookahead)
-    return jnp.tril(out), info
+    with jax.named_scope("potrf_epilogue"):
+        return jnp.tril(out), info
 
 
 @accurate_matmuls
@@ -293,27 +296,30 @@ def potrf(A: TiledMatrix, opts: Options = DEFAULT_OPTIONS
     # bench sizes (round-5 driver-overhead profiling). Upper storage
     # reaches the lower triangle by conjugate-transposing the raw
     # storage instead of mirroring.
-    if A.uplo is Uplo.Upper:
-        a = jnp.conj(A.dense_canonical()).T
-    else:
-        a = A.dense_canonical()
-    # zpotrf contract (full_dense used to realify; the raw storage
-    # path must do it explicitly)
-    a = tile_ops.realify_diag(a)
-    a = unit_pad_diag(a, n, n)
+    with jax.named_scope("potrf_prologue"):
+        if A.uplo is Uplo.Upper:
+            a = jnp.conj(A.dense_canonical()).T
+        else:
+            a = A.dense_canonical()
+        # zpotrf contract (full_dense used to realify; the raw storage
+        # path must do it explicitly)
+        a = tile_ops.realify_diag(a)
+        a = unit_pad_diag(a, n, n)
     nt = A.mt
     with blocked.distribute_on(A.grid):
         lower, info = _potrf_blocked(a, nb, nt, prec=opts.update_precision,
                                      iter_large=opts.factor_iter_large,
                                      lookahead=normalize_lookahead(
                                          opts.lookahead))
-    if A.uplo is Uplo.Upper:
-        out = from_dense(jnp.conj(lower).T, nb, grid=A.grid,
-                         kind=MatrixKind.Triangular, uplo=Uplo.Upper,
-                         logical_shape=(n, n))
-    else:
-        out = from_dense(lower, nb, grid=A.grid, kind=MatrixKind.Triangular,
-                         uplo=Uplo.Lower, logical_shape=(n, n))
+    with jax.named_scope("potrf_epilogue"):
+        if A.uplo is Uplo.Upper:
+            out = from_dense(jnp.conj(lower).T, nb, grid=A.grid,
+                             kind=MatrixKind.Triangular, uplo=Uplo.Upper,
+                             logical_shape=(n, n))
+        else:
+            out = from_dense(lower, nb, grid=A.grid,
+                             kind=MatrixKind.Triangular, uplo=Uplo.Lower,
+                             logical_shape=(n, n))
     return out, info
 
 
@@ -323,12 +329,11 @@ def potrs(L: TiledMatrix, B: TiledMatrix,
     src/potrs.cc: two work::trsm sweeps)."""
     if L.kind is not MatrixKind.Triangular:
         raise SlateError("potrs: L must be the factor from potrf")
-    if L.uplo is Uplo.Lower:
-        y = blas3.trsm(Side.Left, 1.0, L, B, opts)
-        x = blas3.trsm(Side.Left, 1.0, L.H, y, opts)
-    else:
-        y = blas3.trsm(Side.Left, 1.0, L.H, B, opts)
-        x = blas3.trsm(Side.Left, 1.0, L, y, opts)
+    lower = L.uplo is Uplo.Lower
+    with jax.named_scope("potrs_fwd"):
+        y = blas3.trsm(Side.Left, 1.0, L if lower else L.H, B, opts)
+    with jax.named_scope("potrs_bwd"):
+        x = blas3.trsm(Side.Left, 1.0, L.H if lower else L, y, opts)
     return x
 
 

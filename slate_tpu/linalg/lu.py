@@ -185,11 +185,12 @@ def _apply_deferred_left_swaps(a: Array, pps, nb: int) -> Array:
     actually moves are gathered. The ragged final column block (if any)
     has no later permutations and is skipped (σ = None)."""
     m = a.shape[0]
-    for j, sig in enumerate(_suffix_perms(pps, m, nb)):
-        if sig is None:
-            continue
-        j0, j1 = j * nb, (j + 1) * nb
-        a = blocked.dus_i32(a, a[:, j0:j1][sig[j1:]], j1, j0)
+    with jax.named_scope("row_swap"):
+        for j, sig in enumerate(_suffix_perms(pps, m, nb)):
+            if sig is None:
+                continue
+            j0, j1 = j * nb, (j + 1) * nb
+            a = blocked.dus_i32(a, a[:, j0:j1][sig[j1:]], j1, j0)
     return a
 
 
@@ -259,8 +260,9 @@ def _getrf_iter(a: Array, nb: int, prec, threshold: float = 1.0,
     nt = w // nb
     dus = blocked.dus_i32  # raw python-int starts lower to s64 under
     # x64 and trip the pre-0.6 partitioner's mixed-width compare
-    perm = jnp.arange(m, dtype=jnp.int32)
-    info = jnp.zeros((), jnp.int32)
+    with jax.named_scope("getrf_prologue"):
+        perm = jnp.arange(m, dtype=jnp.int32)
+        info = jnp.zeros((), jnp.int32)
     pps = []
 
     def factor_panel(panel: Array, prows: int):
@@ -277,8 +279,10 @@ def _getrf_iter(a: Array, nb: int, prec, threshold: float = 1.0,
         if threshold < 1.0:
             p_p = _tournament_perm(panel, nb, nb, prows, m,
                                    batched=tournament_batched)
+            with jax.named_scope("row_swap"):
+                pan_p = panel[p_p]
             lu_p, _, i_p = _tournament_panel(
-                panel[p_p], nb, nb, prows, perm_done=True)
+                pan_p, nb, nb, prows, perm_done=True)
             return lu_p, p_p, i_p
         hb = blocked.bucket_pow2(prows, nb)
         if hb > prows:
@@ -291,37 +295,50 @@ def _getrf_iter(a: Array, nb: int, prec, threshold: float = 1.0,
         k0, k1 = k * nb, (k + 1) * nb
         rows = m - k0
         if ahead is None:
-            with jax.named_scope(f"getrf_l{k}_panel"):
+            panel_scope = f"getrf_l{k}_panel"
+            with jax.named_scope(panel_scope):
                 lu_p, p_p, i_p = factor_panel(a[k0:, k0:k1], rows)
         else:
+            panel_scope = f"getrf_l{k}_panel_lookahead"
             lu_p, p_p, i_p = ahead
             ahead = None
-        info = jnp.where((info == 0) & (i_p > 0), k0 + i_p,
-                         info).astype(jnp.int32)
-        perm = perm.at[k0:].set(perm[k0:][p_p])
+        with jax.named_scope(f"getrf_l{k}_store"):
+            info = jnp.where((info == 0) & (i_p > 0), k0 + i_p,
+                             info).astype(jnp.int32)
+            with jax.named_scope("row_swap"):
+                perm = perm.at[k0:].set(perm[k0:][p_p])
+            if not fused:
+                # legacy materialized path (reference arm for the A/B
+                # and the bit-equivalence tests): permute the whole
+                # remaining row block, stored L included, then update
+                # in place
+                moved = blocked.permute_rows_limited(a[k0:, :], p_p,
+                                                     2 * nb)
+                a = dus(a, moved, k0, 0)
+            a = dus(a, lu_p, k0, k0)
         pps.append(p_p)
-        if not fused:
-            # legacy materialized path (reference arm for the A/B and
-            # the bit-equivalence tests): permute the whole remaining
-            # row block, stored L included, then update in place
-            moved = blocked.permute_rows_limited(a[k0:, :], p_p, 2 * nb)
-            a = dus(a, moved, k0, 0)
-        a = dus(a, lu_p, k0, k0)
         if k1 >= w:
             continue
-        l11 = jnp.tril(lu_p[:nb], -1) + jnp.eye(nb, dtype=a.dtype)
-        inv11 = blocked.trtri_lower_batched(l11, unit=True)
+        # the panel's L11 inverse belongs to its panel, as potrf's
+        # tile inverse sits in potrf_l<k>_panel
+        with jax.named_scope(panel_scope):
+            l11 = jnp.tril(lu_p[:nb], -1) + jnp.eye(nb, dtype=a.dtype)
+            inv11 = blocked.trtri_lower_batched(l11, unit=True)
         if fused and lookahead >= 1 and k1 + nb < w:
-            right = a[k0:, k1:]
-            top = right[p_p[:nb]]  # pivot rows, one thin gather
+            with jax.named_scope(f"getrf_l{k}_load"):
+                right = a[k0:, k1:]
+                with jax.named_scope("row_swap"):
+                    top = right[p_p[:nb]]  # pivot rows, one thin gather
             # (a) next-panel columns: the thin nb-wide trailing slab
             with jax.named_scope(f"getrf_l{k}_trail_next"):
                 u12n = blocked.mm(inv11, top[:, :nb], prec)
+                with jax.named_scope("row_swap"):
+                    rows_n = right[:, :nb][p_p[nb:]]
                 schur_n = blocked.rebalance(
-                    right[:, :nb][p_p[nb:]]
-                    - blocked.mm(lu_p[nb:], u12n, prec))
-            a = dus(a, u12n, k0, k1)
-            a = dus(a, schur_n, k1, k1)
+                    rows_n - blocked.mm(lu_p[nb:], u12n, prec))
+            with jax.named_scope(f"getrf_l{k}_store"):
+                a = dus(a, u12n, k0, k1)
+                a = dus(a, schur_n, k1, k1)
             # (b) factor panel k+1 from the fresh slab — the serial
             # pivot/column chain, no data edge to the remainder gemms
             with jax.named_scope(f"getrf_l{k + 1}_panel_lookahead"):
@@ -329,27 +346,37 @@ def _getrf_iter(a: Array, nb: int, prec, threshold: float = 1.0,
             # (c) the remainder slab, independent of (b)
             with jax.named_scope(f"getrf_l{k}_trail_rest"):
                 u12r = blocked.mm(inv11, top[:, nb:], prec)
+                with jax.named_scope("row_swap"):
+                    rows_r = right[:, nb:][p_p[nb:]]
                 schur_r = blocked.rebalance(
-                    right[:, nb:][p_p[nb:]]
-                    - blocked.mm(lu_p[nb:], u12r, prec))
-            a = dus(a, u12r, k0, k1 + nb)
-            a = dus(a, schur_r, k1, k1 + nb)
+                    rows_r - blocked.mm(lu_p[nb:], u12r, prec))
+            with jax.named_scope(f"getrf_l{k}_store"):
+                a = dus(a, u12r, k0, k1 + nb)
+                a = dus(a, schur_r, k1, k1 + nb)
         elif fused:
             with jax.named_scope(f"getrf_l{k}_trail"):
                 right = a[k0:, k1:]
-                u12 = blocked.mm(inv11, right[p_p[:nb]], prec)
+                with jax.named_scope("row_swap"):
+                    top = right[p_p[:nb]]
+                u12 = blocked.mm(inv11, top, prec)
+                a = dus(a, u12, k0, k1)
+                with jax.named_scope("row_swap"):
+                    rows = right[p_p[nb:]]
+                schur = blocked.rebalance(
+                    rows - blocked.mm(lu_p[nb:], u12, prec))
+            with jax.named_scope(f"getrf_l{k}_store"):
+                a = dus(a, schur, k1, k1)
+        else:
+            with jax.named_scope(f"getrf_l{k}_trail"):
+                u12 = blocked.mm(inv11, a[k0:k1, k1:], prec)
                 a = dus(a, u12, k0, k1)
                 schur = blocked.rebalance(
-                    right[p_p[nb:]] - blocked.mm(lu_p[nb:], u12, prec))
-            a = dus(a, schur, k1, k1)
-        else:
-            u12 = blocked.mm(inv11, a[k0:k1, k1:], prec)
-            a = dus(a, u12, k0, k1)
-            schur = blocked.rebalance(
-                a[k1:, k1:] - blocked.mm(a[k1:, k0:k1], u12, prec))
-            a = dus(a, schur, k1, k1)
+                    a[k1:, k1:] - blocked.mm(a[k1:, k0:k1], u12, prec))
+            with jax.named_scope(f"getrf_l{k}_store"):
+                a = dus(a, schur, k1, k1)
     if fused:
-        a = _apply_deferred_left_swaps(a, pps, nb)
+        with jax.named_scope("getrf_epilogue"):
+            a = _apply_deferred_left_swaps(a, pps, nb)
     return a, perm, info
 
 
@@ -402,8 +429,9 @@ def getrf(A: TiledMatrix, opts: Options = DEFAULT_OPTIONS
     if method is MethodLU.CALU:
         return getrf_tntpiv(A, opts)
     m, n = A.shape
-    a = _canonical(A)
-    a = _pad_identity_diag(a, m, n)
+    with jax.named_scope("getrf_prologue"):
+        a = _canonical(A)
+        a = _pad_identity_diag(a, m, n)
     with blocked.distribute_on(A.grid):
         lu, perm, info = _getrf_blocked(
             a, A.nb, min(A.mt, A.nt),
@@ -414,7 +442,8 @@ def getrf(A: TiledMatrix, opts: Options = DEFAULT_OPTIONS
             iter_large=opts.factor_iter_large,
             lookahead=normalize_lookahead(opts.lookahead),
             tournament_batched=opts.lu_tournament_batched)
-    out = from_dense(lu, A.nb, grid=A.grid, logical_shape=(m, n))
+    with jax.named_scope("getrf_epilogue"):
+        out = from_dense(lu, A.nb, grid=A.grid, logical_shape=(m, n))
     return out, perm, info
 
 
@@ -669,34 +698,43 @@ def getrs(LU: TiledMatrix, perm: Array, B: TiledMatrix,
           ) -> TiledMatrix:
     """Solve A·X = B (or Aᵀ·X = B) from getrf factors (slate::getrs,
     src/getrs.cc: permuteRows → trsm(L) → trsm(U))."""
-    lu = LU.dense_canonical()
-    # storage beyond the logical shape is zero by invariant; restore the
-    # unit diagonal there so the padded triangular solves stay exact
-    lu = _pad_identity_diag(lu, *LU.shape)
-    b = B.dense_canonical()
-    if b.shape[0] != lu.shape[0]:
-        pad = lu.shape[0] - b.shape[0]
-        if pad < 0:
-            raise SlateError("getrs: rhs taller than factor")
-        b = jnp.pad(b, ((0, pad), (0, 0)))
     prec = opts.update_precision
-    if not trans:
-        # same fusion contract as the factorization's trailing reads:
-        # b[perm] is ONE gather feeding the first trsm's operand (XLA
-        # fuses it into the solve's reads) — never a per-level copy
-        pb = b[perm]
-        y = blocked.trsm_rec(lu, pb, left=True, lower=True, unit=True,
-                             prec=prec, base=LU.nb)
-        x = blocked.trsm_rec(lu, y, left=True, lower=False, unit=False,
-                             prec=prec, base=LU.nb)
-    else:
-        z = blocked.trsm_rec(lu, b, left=True, lower=False, unit=False,
-                             trans_a=True, prec=prec, base=LU.nb)
-        w = blocked.trsm_rec(lu, z, left=True, lower=True, unit=True,
-                             trans_a=True, prec=prec, base=LU.nb)
-        x = jnp.zeros_like(w).at[perm].set(w)
-    x = x[: B.dense_canonical().shape[0]]
-    return from_dense(x, B.nb, grid=B.grid, logical_shape=B.shape)
+    with jax.named_scope("getrs_fwd"):
+        lu = LU.dense_canonical()
+        # storage beyond the logical shape is zero by invariant; restore
+        # the unit diagonal there so the padded triangular solves stay
+        # exact
+        lu = _pad_identity_diag(lu, *LU.shape)
+        b = B.dense_canonical()
+        if b.shape[0] != lu.shape[0]:
+            pad = lu.shape[0] - b.shape[0]
+            if pad < 0:
+                raise SlateError("getrs: rhs taller than factor")
+            b = jnp.pad(b, ((0, pad), (0, 0)))
+        if not trans:
+            # same fusion contract as the factorization's trailing
+            # reads: b[perm] is ONE gather feeding the first trsm's
+            # operand (XLA fuses it into the solve's reads) — never a
+            # per-level copy
+            with jax.named_scope("row_swap"):
+                pb = b[perm]
+            y = blocked.trsm_rec(lu, pb, left=True, lower=True, unit=True,
+                                 prec=prec, base=LU.nb)
+        else:
+            y = blocked.trsm_rec(lu, b, left=True, lower=False,
+                                 unit=False, trans_a=True, prec=prec,
+                                 base=LU.nb)
+    with jax.named_scope("getrs_bwd"):
+        if not trans:
+            x = blocked.trsm_rec(lu, y, left=True, lower=False,
+                                 unit=False, prec=prec, base=LU.nb)
+        else:
+            w = blocked.trsm_rec(lu, y, left=True, lower=True, unit=True,
+                                 trans_a=True, prec=prec, base=LU.nb)
+            with jax.named_scope("row_swap"):
+                x = jnp.zeros_like(w).at[perm].set(w)
+        x = x[: B.dense_canonical().shape[0]]
+        return from_dense(x, B.nb, grid=B.grid, logical_shape=B.shape)
 
 
 def gesv(A: TiledMatrix, B: TiledMatrix, opts: Options = DEFAULT_OPTIONS

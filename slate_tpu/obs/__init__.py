@@ -17,11 +17,11 @@ tools ingest:
 * :mod:`.exposition` — Prometheus text rendering of runtime Metrics +
   an opt-in stdlib-only HTTP endpoint (/metrics, /healthz,
   /trace.json).
-* :mod:`.merge`      — aligns host spans with ``jax.profiler`` device
-  traces via the ``potrf_l{k}_*``/``geqrf_l{k}_*`` named scopes and
-  computes the measured lookahead-overlap metric (PERF_HISTORY.md round 7's
-  modeled number, measured); round 12 adds the multi-process trace
-  combine (``combine_process_traces``).
+* :mod:`.merge`      — reads ``jax.profiler`` device traces via the
+  ``potrf_l{k}_*``/``geqrf_l{k}_*`` named scopes and computes the
+  measured lookahead-overlap metric (PERF_HISTORY.md round 7's modeled
+  number, measured); round 12 adds the multi-process trace combine
+  (``combine_process_traces``).
 * :mod:`.slo`        — declarative serving objectives evaluated over
   rolling windows with multi-window burn rates; the ``/slo`` endpoint
   payload (round 12).
@@ -74,7 +74,7 @@ from .exposition import ObsServer, render_prometheus
 from .forecast import Forecaster, forecast_points, validate_forecast
 from .timeseries import (SessionSampler, TimeseriesStore,
                          validate_timeseries)
-from .merge import combine_process_traces, lookahead_overlap, merge_traces
+from .merge import combine_process_traces, lookahead_overlap
 from .numerics import NumericsConfig, NumericsMonitor
 from .recorder import (DecisionJournal, FlightRecorder, IncidentCapture,
                        Recorder)
@@ -93,7 +93,7 @@ __all__ = [
     "costs", "default_tracer", "events", "flops", "forecast",
     "forecast_points", "journal_digest",
     "lookahead_overlap",
-    "merge_traces", "numerics", "recorder", "render_prometheus",
+    "numerics", "recorder", "render_prometheus",
     "roofline", "slo", "timeseries",
     "validate_chrome_trace", "validate_forecast", "validate_incident",
     "validate_timeseries", "watchdog",

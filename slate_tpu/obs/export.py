@@ -26,7 +26,6 @@ REQUIRED_KEYS = ("ph", "ts", "dur", "pid", "tid", "name", "args")
 
 HOST_PID = 0
 PHASE_PID = 1
-DEVICE_PID = 2  # used by obs.merge for re-based jax.profiler events
 
 
 def chrome_trace(spans: Iterable, t0: Optional[float] = None) -> dict:

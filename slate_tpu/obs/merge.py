@@ -1,29 +1,26 @@
-"""Host/device trace merging + the measured lookahead-overlap metric.
+"""Device-trace reading + the measured lookahead-overlap metric, and the
+multi-process trace combine.
 
 ``jax.profiler`` captures device timelines; exported through the
 TensorBoard profile plugin (or ``trace_event`` conversion) they arrive
 as Chrome-trace JSON whose event names carry our ``jax.named_scope``
 labels — the per-level ``potrf_l{k}_tile/_panel/_trail_next/_trail_rest
 /_l{k+1}_tile_lookahead`` (linalg/cholesky.py) and ``geqrf_l{k}_*``
-(linalg/qr.py) scopes the round-7 pipeline plants. This module does two
-things with them:
+(linalg/qr.py) scopes the round-7 pipeline plants. From them
+:func:`lookahead_overlap` computes the MEASURED version of the number
+PERF_HISTORY.md round 7 only models: for each level k, how much of the
+level-(k+1) lookahead panel's device time runs CONCURRENTLY with the
+level-k remainder ("trail_rest") gemms. ``overlap_fraction`` = hidden
+panel seconds / total lookahead-panel seconds: 1.0 means the panel
+chain is fully hidden (the per-level floor is max(panel, trailing)),
+0.0 means the schedule serialized (the floor degrades to their sum).
 
-* :func:`lookahead_overlap` — the MEASURED version of the number
-  PERF_HISTORY.md round 7 only models: for each level k, how much of the
-  level-(k+1) lookahead panel's device time runs CONCURRENTLY with the
-  level-k remainder ("trail_rest") gemms. ``overlap_fraction`` = hidden
-  panel seconds / total lookahead-panel seconds: 1.0 means the panel
-  chain is fully hidden (the per-level floor is max(panel, trailing)),
-  0.0 means the schedule serialized (the floor degrades to their sum).
-
-* :func:`merge_traces` — re-bases a device-trace event list into a host
-  span export (pid 2, "device"), aligning the earliest device event to
-  a named host anchor span, so one Perfetto load shows request → batch
-  → factor host spans above the device lanes they dispatched.
-
-Both work on any ``trace_event`` JSON (dict with ``traceEvents`` or a
+It works on any ``trace_event`` JSON (dict with ``traceEvents`` or a
 bare list), gzipped or not — :func:`load_trace` /
-:func:`find_device_traces` handle the profiler's output layout.
+:func:`find_device_traces` handle the profiler's output layout. Host
+spans need no merging onto a device trace: while the JAX profiler
+records, each ``Tracer.span`` is also a ``jax.profiler.TraceAnnotation``
+(obs.tracing), on the profiler's own clock.
 """
 
 from __future__ import annotations
@@ -34,8 +31,6 @@ import json
 import os
 import re
 from typing import Dict, Iterable, List, Optional, Tuple
-
-from .export import DEVICE_PID
 
 SCOPE_RE = re.compile(r"(potrf|getrf|geqrf)_l(\d+)_([a-zA-Z0-9_]+)")
 
@@ -187,8 +182,8 @@ def lookahead_overlap(events: Iterable[dict], driver: str = "potrf") -> dict:
 # -- multi-process combine (round 12: obs.aggregate's trace half) ------------
 
 # pid namespace stride per process: every process emits pids 0 (host
-# threads), 1 (phase lanes), 2 (re-based device lanes) — see
-# obs.export; 100 leaves room for any future lane class
+# threads) and 1 (phase lanes) — see obs.export; 100 leaves room for
+# any future lane class
 _PROC_PID_STRIDE = 100
 
 
@@ -227,40 +222,4 @@ def combine_process_traces(traces: Iterable, labels: Optional[List[str]]
     # the chrome validator (and readers) expect "X" events in ts order;
     # metadata first, as obs.export emits them
     out.sort(key=lambda e: (e.get("ph") != "M", e.get("ts", 0)))
-    return {"traceEvents": out, "displayTimeUnit": "ms"}
-
-
-# -- host/device merge -------------------------------------------------------
-
-
-def merge_traces(host_trace, device_events: Iterable[dict],
-                 anchor: Optional[str] = None) -> dict:
-    """One Chrome trace with the device lanes under the host spans.
-
-    ``host_trace`` is a chrome_trace() dict (or event list); device
-    events are re-based into pid ``DEVICE_PID`` with their earliest
-    timestamp aligned to the start of the first host event named
-    ``anchor`` (default: the earliest host event) — the coarse clock
-    alignment the jax-profiler/host perf_counter pair allows without a
-    shared timebase."""
-    host = events_of(host_trace)
-    dev = [dict(e) for e in events_of(device_events)
-           if e.get("ph") in (None, "X", "M")]
-    host_x = [e for e in host if e.get("ph") == "X"]
-    anchor_ts = 0.0
-    if host_x:
-        anchored = [e for e in host_x if anchor and e.get("name") == anchor]
-        anchor_ts = (anchored or host_x)[0]["ts"]
-    dev_x = [e for e in dev if e.get("ph", "X") == "X"
-             and e.get("ts") is not None]
-    shift = anchor_ts - min((e["ts"] for e in dev_x), default=0.0)
-    out = list(host)
-    out.append({"ph": "M", "ts": 0, "pid": DEVICE_PID, "tid": 0,
-                "name": "process_name", "args": {"name": "device"}})
-    for e in dev:
-        e["pid"] = DEVICE_PID
-        if e.get("ts") is not None and e.get("ph", "X") == "X":
-            e["ts"] = e["ts"] + shift
-        e.setdefault("args", {})
-        out.append(e)
     return {"traceEvents": out, "displayTimeUnit": "ms"}

@@ -14,8 +14,13 @@ Chrome-trace JSON (obs.export) next to the legacy SVG.
 Design rules:
 
 * **Disabled is free.** ``Tracer.span`` returns a shared no-op context
-  manager when tracing is off — no Span allocation, no id counter
-  bump, no lock. The runtime's hot path stays at its round-6 cost.
+  manager when tracing is off and the JAX profiler is not recording —
+  no Span allocation, no id counter bump, no lock. The runtime's hot
+  path stays at its round-6 cost.
+* **On the profiler's clock.** While the JAX profiler records, every
+  ``Tracer.span`` also opens a ``jax.profiler.TraceAnnotation`` of the
+  same name, with obs tracing on or off, so a served request's host
+  spans land in the device trace itself, beside the ops they dispatched.
 * **One clock, every view.** A finished span also feeds the legacy
   ``trace.timers`` map and (when ``trace.Trace`` is on) the SVG event
   list, so enabling spans never *loses* the coarse views — the span
@@ -107,6 +112,43 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+_profiling = None
+
+
+def profiling() -> bool:
+    """True while the JAX profiler records a trace (one C++ call; the
+    probe resolves lazily so importing obs never imports jax)."""
+    global _profiling
+    if _profiling is None:
+        from jax.profiler import TraceAnnotation
+        _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+class _Annotated:
+    """A span as the profiler sees it: a ``TraceAnnotation`` of the
+    span's name around the block, and, with obs tracing on, the live
+    span inside it (else the shared no-op span)."""
+
+    __slots__ = ("_name", "_inner", "_annotation")
+
+    def __init__(self, name: str, inner):
+        self._name = name
+        self._inner = inner
+        self._annotation = None
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
+        return self._inner.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return self._inner.__exit__(*exc)
+        finally:
+            self._annotation.__exit__(*exc)
+
 
 class _SpanCtx:
     """Context manager for one live span: enters the contextvar scope
@@ -180,12 +222,21 @@ class Tracer:
     def current(self) -> Optional[Span]:
         return self._current.get()
 
+    @property
+    def active(self) -> bool:
+        """Whether ``span`` records anything: obs tracing is on or the
+        JAX profiler is recording."""
+        return self.enabled or profiling()
+
     def span(self, name: str, kind: str = "internal", **attrs):
         """Context manager; yields the live Span (or the shared no-op
-        when tracing is disabled — zero allocation)."""
+        when tracing is disabled — zero allocation). While the JAX
+        profiler records, the block is also a ``TraceAnnotation`` of
+        ``name``."""
         if not self.enabled:
-            return NOOP_SPAN
-        return _SpanCtx(self, self.start_span(name, kind=kind, **attrs))
+            return _Annotated(name, NOOP_SPAN) if profiling() else NOOP_SPAN
+        ctx = _SpanCtx(self, self.start_span(name, kind=kind, **attrs))
+        return _Annotated(name, ctx) if profiling() else ctx
 
     def start_span(self, name: str, parent: Optional[Span] = None,
                    kind: str = "internal", **attrs) -> Optional[Span]:

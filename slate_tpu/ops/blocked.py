@@ -569,11 +569,12 @@ def _panel_getrf_base(a: Array) -> Tuple[Array, Array, Array]:
         score = jnp.where(rows >= j, jnp.abs(col), -1.0)
         p = jnp.argmax(score).astype(jnp.int32)
         # swap rows j <-> p (reads before writes; p == j is a no-op)
-        row_j = a[j, :]
-        row_p = a[p, :]
-        a = a.at[j, :].set(row_p).at[p, :].set(row_j)
-        pj, pp = perm[j], perm[p]
-        perm = perm.at[j].set(pp).at[p].set(pj)
+        with jax.named_scope("row_swap"):
+            row_j = a[j, :]
+            row_p = a[p, :]
+            a = a.at[j, :].set(row_p).at[p, :].set(row_j)
+            pj, pp = perm[j], perm[p]
+            perm = perm.at[j].set(pp).at[p].set(pj)
         d = a[j, j]
         bad = jnp.isnan(jnp.abs(d)) | (jnp.abs(d) == 0)
         info = jnp.where((info == 0) & bad, j + 1, info)
@@ -611,7 +612,8 @@ def permute_rows_limited(x: Array, perm: Array, max_moved: int) -> Array:
     remains in the recursion (_getrf_rec), the legacy arm
     (Options.lu_pivot_fusion=False), and the wide-matrix rest solve."""
     del max_moved
-    return x[perm]
+    with jax.named_scope("row_swap"):
+        return x[perm]
 
 
 def lift_tail_perm(p_tail: Array, h: int, m: int, dtype=None) -> Array:
@@ -639,7 +641,8 @@ def lift_tail_perm(p_tail: Array, h: int, m: int, dtype=None) -> Array:
 
 def _compose_tail(p1: Array, p2: Array, h: int) -> Array:
     """Total gather perm for 'apply p1, then p2 on rows h:'."""
-    return p1[lift_tail_perm(p2, h, p1.shape[0], p1.dtype)]
+    with jax.named_scope("row_swap"):
+        return p1[lift_tail_perm(p2, h, p1.shape[0], p1.dtype)]
 
 
 def panel_getrf(a: Array, ib: int = PANEL_IB,
@@ -749,10 +752,11 @@ def _panel_getrf_batched_impl(stack: Array):
         score = jnp.where(iot >= j, jnp.abs(col), -1.0).astype(rdtype)
         p = jnp.argmax(score, axis=1).astype(jnp.int32)           # (B,)
         # swap rows j <-> p_b as ONE gather of a swapped index map
-        idx = jnp.where(iot == j, p[:, None], iot)
-        idx = jnp.where(iot == p[:, None], j, idx)    # p == j stays j
-        a = jnp.take_along_axis(a, idx[:, :, None], axis=1)
-        perm = jnp.take_along_axis(perm, idx, axis=1)
+        with jax.named_scope("row_swap"):
+            idx = jnp.where(iot == j, p[:, None], iot)
+            idx = jnp.where(iot == p[:, None], j, idx)  # p == j stays j
+            a = jnp.take_along_axis(a, idx[:, :, None], axis=1)
+            perm = jnp.take_along_axis(perm, idx, axis=1)
         d = jnp.take_along_axis(col, p[:, None], axis=1)[:, 0]    # (B,)
         bad = jnp.isnan(jnp.abs(d)) | (jnp.abs(d) == 0)
         info = jnp.where((info == 0) & bad, j + 1, info).astype(jnp.int32)

@@ -779,7 +779,7 @@ class Batcher:
         tr = self.session.tracer
         bctx = (tr.span("serve.batch", handle=repr(handle),
                         batch_size=len(live), shape=list(kshape),
-                        dtype=kdtype) if tr.enabled else _NOOP_SPAN)
+                        dtype=kdtype) if tr.active else _NOOP_SPAN)
         m = self.session.metrics
         attr = self.session.attribution
         with bctx as bspan:
@@ -920,7 +920,7 @@ class Batcher:
         tr = self.session.tracer
         bctx = (tr.span("serve.batch", op=op, n=n, grouped=True,
                         batch_size=len(live), shape=list(shape),
-                        dtype=bdt) if tr.enabled else _NOOP_SPAN)
+                        dtype=bdt) if tr.active else _NOOP_SPAN)
         m = self.session.metrics
         attr = self.session.attribution
         with bctx as bspan:
@@ -1013,7 +1013,7 @@ class Batcher:
         m.inc("degraded_dispatches_total")
         bctx = (tr.span("serve.batch.degraded", batch_size=len(live),
                         ladder="per_request")
-                if tr.enabled else _NOOP_SPAN)
+                if tr.active else _NOOP_SPAN)
         with bctx as bspan:
             tid = getattr(bspan, "trace_id", None)
             for r in live:

@@ -2186,8 +2186,9 @@ class Session:
             if tenant is not None:
                 kw["tenant"] = tenant
             X = self.solve_matrix(handle, B, **kw)
-            x = (_host_crop(X) if isinstance(X, TiledMatrix)
-                 else np.asarray(X)[: entry.n])
+            with self.tracer.span("serve.crop"):
+                x = (_host_crop(X) if isinstance(X, TiledMatrix)
+                     else np.asarray(X)[: entry.n])
             return x[:, 0] if vector else x
 
     # -- the many-small-problems engine (round 10) -------------------------

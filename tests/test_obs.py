@@ -413,21 +413,36 @@ def test_lookahead_overlap_metric():
     assert ova["overlap_fraction"] == pytest.approx(1.0)
 
 
-def test_merge_traces_rebases_device_lane():
-    tracer = Tracer().on()
-    with tracer.span("serve.factor"):
-        time.sleep(0.001)
-    host = obs.chrome_trace(tracer.spans())
-    dev = [_dev_event("potrf_l0_panel", 5000, 100)]
-    merged = obs.merge_traces(host, dev, anchor="serve.factor")
-    ev = merged["traceEvents"]
-    dev_x = [e for e in ev if e["pid"] == 2 and e.get("ph") == "X"]
-    host_factor = [e for e in ev if e.get("name") == "serve.factor"]
-    assert dev_x and host_factor
-    # earliest device event aligned onto the anchor span's start
-    assert dev_x[0]["ts"] == pytest.approx(host_factor[0]["ts"])
-    assert any(e["pid"] == 2 and e.get("name") == "process_name"
-               for e in ev)
+def test_served_spans_on_the_profiler_clock(tmp_path):
+    """While the JAX profiler records, the serving spans are its own
+    host events (TraceAnnotations), with obs tracing off: a device
+    trace shows what the host did in each gap with no clock to align."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracer = Tracer()  # obs tracing stays off
+    sess, h, a = _lu_session(tracer=tracer)
+    ex = Executor(sess, max_batch=4, max_wait=0.001)
+    try:
+        ex.warmup([h])
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for f in [ex.submit(h, RNG.standard_normal(N))
+                      for _ in range(3)]:
+                f.result(timeout=60)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        ex.shutdown()
+    assert tracer.spans() == []
+    assert tracer.span("after") is obs.NOOP_SPAN  # off again, free again
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {p.name: p for p in ProfileData.from_file(path).planes}["/host:CPU"]
+    names = {e.name for line in host.lines for e in line.events}
+    assert {"serve.batch", "serve.dispatch", "serve.block",
+            "serve.crop"} <= names
 
 
 # -- review-fix regression pins ---------------------------------------------
