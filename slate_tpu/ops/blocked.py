@@ -20,7 +20,9 @@ trailing updates (src/internal/internal_herk.cc:351):
   a fori_loop substitution on a ≤64 block.
 - ``trsm_rec`` — triangular solve by block-column recursion; base case
   multiplies by the inverse of an nb-sized diagonal block (the same
-  inverted-diagonal-block scheme cuBLAS/MAGMA use for GPU trsm).
+  inverted-diagonal-block scheme cuBLAS/MAGMA use for GPU trsm), all
+  of a sweep's blocks inverted up front in one batched call where the
+  shape allows.
 - ``herk_lower_rec`` — rank-k update computing only the lower triangle
   (recursive split; off-diagonal blocks are plain gemms), halving the
   trailing-update flops of potrf exactly like the reference's herk.
@@ -220,16 +222,15 @@ def trtri_rec(a: Array, lower: bool = True, unit: bool = False,
     return trtri_lower_rec(a.T, unit, base).T
 
 
-@functools.partial(jax.jit, static_argnames=("unit",))
-def _trtri_block(l: Array, unit: bool) -> Array:
-    """jit-cached lower-triangular block inverse: trsm bases hit the same
-    (TRSM_BASE, TRSM_BASE) shape hundreds of times per factorization —
-    one compilation, many call sites."""
-    return trtri_lower_rec(l, unit)
+def _pow2_leaves(n: int, leaf: int) -> bool:
+    """n is a power-of-two number (≥ 2) of leaf blocks: the shapes
+    trtri_lower_batched batches."""
+    q = n // leaf
+    return n > leaf and n % leaf == 0 and q & (q - 1) == 0
 
 
 def trtri_lower_batched(l: Array, unit: bool = False,
-                        leaf: int = 64) -> Array:
+                        leaf: int = TRTRI_BASE) -> Array:
     """inv(L) with ALL diagonal leaf blocks inverted in one vmapped
     straight-line kernel, then combined by the 2×2 gemm recursion.
 
@@ -239,35 +240,43 @@ def trtri_lower_batched(l: Array, unit: bool = False,
     kernel + log2(n/leaf) combine levels of MXU gemms. This is the
     panel-inverse kernel of the iterative potrf/getrf paths (the
     inverted-diagonal-block scheme cuBLAS/MAGMA use for GPU trsm, done
-    once per panel instead of once per trsm call)."""
-    n = l.shape[0]
-    nleaf = n // leaf if n % leaf == 0 else 0
-    if n <= leaf or nleaf == 0 or (nleaf & (nleaf - 1)) != 0:
+    once per panel instead of once per trsm call).
+
+    A (..., n, n) stack (of power-of-two leaf grids) inverts every
+    block at once: one leaf kernel over all its leaves, each combine
+    level one gemm batched over blocks and pairs (trsm_rec's diagonal
+    blocks)."""
+    n = l.shape[-1]
+    if not _pow2_leaves(n, leaf):
         return trtri_lower_rec(l, unit)  # needs a power-of-two leaf grid
-    idx = jnp.arange(nleaf) * leaf
-    diags = jax.vmap(
-        lambda i: lax.dynamic_slice(l, (i, i), (leaf, leaf)))(idx)
-    inv_leaves = jax.vmap(lambda d: _trtri_unrolled_u(d, leaf, unit))(diags)
+    diags = _blocks(l, leaf, leaf)  # (..., nleaf, leaf, leaf)
+    inv = jax.vmap(lambda d: _trtri_unrolled_u(d, leaf, unit))(
+        diags.reshape((-1, leaf, leaf))).reshape(diags.shape)
 
     # bottom-up assembly: at each level, pair up the current inverses —
     # inv([[A,0],[B,C]]) = [[iA, 0], [−iC·B·iA, iC]]
-    inv = inv_leaves  # (nblk, s, s)
     s = leaf
     while s < n:
-        nblk = inv.shape[0]
-        ia = inv[0::2]  # (nblk/2, s, s)
-        ic = inv[1::2]
-        starts = jnp.arange(nblk // 2) * (2 * s)
-        b = jax.vmap(
-            lambda i: lax.dynamic_slice(l, (i + s, i), (s, s)))(starts)
-        off = -jnp.einsum("bij,bjk,bkl->bil", ic, b, ia,
+        pairs = inv.reshape(inv.shape[:-3] + (-1, 2, s, s))
+        ia, ic = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        off = -jnp.einsum("...ij,...jk,...kl->...il", ic,
+                          _blocks(l, s, 2 * s, s), ia,
                           precision=lax.Precision.HIGHEST)
-        top = jnp.concatenate(
-            [ia, jnp.zeros((nblk // 2, s, s), l.dtype)], axis=2)
-        bot = jnp.concatenate([off, ic], axis=2)
-        inv = jnp.concatenate([top, bot], axis=1)
+        top = jnp.concatenate([ia, jnp.zeros_like(ia)], axis=-1)
+        bot = jnp.concatenate([off, ic], axis=-1)
+        inv = jnp.concatenate([top, bot], axis=-2)
         s *= 2
-    return inv[0]
+    return inv[..., 0, :, :]
+
+
+def _blocks(x: Array, s: int, step: int, down: int = 0) -> Array:
+    """The s×s blocks of x's last two axes at rows i + down, columns i,
+    for i = 0, step, 2·step, …, stacked on a new axis before those two.
+    Static slices, which XLA copies in one fusion; a vmapped
+    dynamic_slice would be a gather, which XLA:TPU runs as a loop over
+    the blocks."""
+    return jnp.stack([x[..., i + down:i + down + s, i:i + s]
+                      for i in range(0, x.shape[-1], step)], axis=-3)
 
 
 def _trtri_unrolled_u(l: Array, ib: int, unit: bool) -> Array:
@@ -288,32 +297,61 @@ def _trtri_unrolled_u(l: Array, ib: int, unit: bool) -> Array:
 # triangular solve
 # ---------------------------------------------------------------------------
 
-def _trsm_left_lower(m: Array, b: Array, unit: bool, prec, base) -> Array:
-    """X with M·X = B, M lower triangular (only lower triangle read)."""
+def _diag_inverses(m: Array, lower: bool, unit: bool, base: int) -> Array:
+    """The inverses of M's n/base diagonal blocks, stacked, from ONE
+    trtri_lower_batched call (upper blocks through their transposes):
+    a leaf's own trtri_lower_rec runs a fori_loop of dependent row
+    substitutions, TRTRI_BASE per TRTRI_BASE rows, block after block."""
+    with jax.named_scope("trsm_diag_inv"):
+        d = _blocks(m, base, base)
+        if lower:
+            return trtri_lower_batched(d, unit)
+        return _bT(trtri_lower_batched(_bT(d), unit))
+
+
+def _trsm_left_lower(m: Array, b: Array, unit: bool, prec, base,
+                     inv: Optional[Array], k: int) -> Array:
+    """X with M·X = B, M lower triangular (only lower triangle read).
+    ``inv[k:]`` are the inverses of M's base-size diagonal blocks (None:
+    each leaf inverts its own block)."""
     n = m.shape[0]
     if n <= base:
-        inv = _trtri_block(m, unit) if n == base \
-            else trtri_lower_rec(m, unit)
-        return mm(inv, b, prec)
+        return mm(trtri_lower_rec(m, unit) if inv is None else inv[k],
+                  b, prec)
     h = _half(n, base)
-    x1 = _trsm_left_lower(m[:h, :h], b[:h], unit, prec, base)
+    x1 = _trsm_left_lower(m[:h, :h], b[:h], unit, prec, base, inv, k)
     rhs2 = b[h:] - mm(m[h:, :h], x1, prec)
-    x2 = _trsm_left_lower(m[h:, h:], rhs2, unit, prec, base)
+    x2 = _trsm_left_lower(m[h:, h:], rhs2, unit, prec, base, inv,
+                          k + h // base)
     return jnp.concatenate([x1, x2], axis=0)
 
 
-def _trsm_left_upper(m: Array, b: Array, unit: bool, prec, base) -> Array:
+def _trsm_left_upper(m: Array, b: Array, unit: bool, prec, base,
+                     inv: Optional[Array], k: int) -> Array:
     n = m.shape[0]
     if n <= base:
-        # inv(U) = inv(Uᵀ)ᵀ so the jit-cached lower kernel serves both
-        inv = _trtri_block(m.T, unit).T if n == base \
-            else trtri_rec(m, lower=False, unit=unit)
-        return mm(inv, b, prec)
+        return mm(trtri_rec(m, lower=False, unit=unit) if inv is None
+                  else inv[k], b, prec)
     h = _half(n, base)
-    x2 = _trsm_left_upper(m[h:, h:], b[h:], unit, prec, base)
+    x2 = _trsm_left_upper(m[h:, h:], b[h:], unit, prec, base, inv,
+                          k + h // base)
     rhs1 = b[:h] - mm(m[:h, h:], x2, prec)
-    x1 = _trsm_left_upper(m[:h, :h], rhs1, unit, prec, base)
+    x1 = _trsm_left_upper(m[:h, :h], rhs1, unit, prec, base, inv, k)
     return jnp.concatenate([x1, x2], axis=0)
+
+
+def _trsm_left(m: Array, b: Array, lower: bool, unit: bool, prec,
+               base: int) -> Array:
+    """X with M·X = B, M triangular. Where M is whole base-size blocks
+    and base a size trtri_lower_batched batches, the sweep's diagonal
+    blocks are inverted up front in one call; else each leaf of the
+    recursion inverts its own (ragged n, base ≤ TRTRI_BASE)."""
+    n = m.shape[0]
+    inv = None
+    if n >= base and n % base == 0 and _pow2_leaves(base, TRTRI_BASE):
+        inv = _diag_inverses(m, lower, unit, base)
+    sweep = _trsm_left_lower if lower else _trsm_left_upper
+    return sweep(m, b, unit, prec, base, inv, 0)
 
 
 def trsm_rec(a: Array, b: Array, *, left: bool = True, lower: bool = True,
@@ -333,16 +371,9 @@ def trsm_rec(a: Array, b: Array, *, left: bool = True, lower: bool = True,
         m = m.T
         eff_lower = not lower
     if left:
-        if eff_lower:
-            return _trsm_left_lower(m, b, unit, prec, base)
-        return _trsm_left_upper(m, b, unit, prec, base)
+        return _trsm_left(m, b, eff_lower, unit, prec, base)
     # right: X·M = B  ⇔  Mᵀ·Xᵀ = Bᵀ
-    mt = m.T
-    if eff_lower:
-        xt = _trsm_left_upper(mt, b.T, unit, prec, base)
-    else:
-        xt = _trsm_left_lower(mt, b.T, unit, prec, base)
-    return xt.T
+    return _trsm_left(m.T, b.T, not eff_lower, unit, prec, base).T
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,8 @@ Mirrors the reference's unit_test/test_Matrix.cc (constructors, views,
 sub, slice, transpose) and test_func.cc (distribution index maps).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -195,3 +197,79 @@ def test_trtri_lower_batched_complex():
         jnp.asarray(l, jnp.complex128)))
     res = np.abs(l @ got - np.eye(n)).max()
     assert res < n * 1e-14 * np.linalg.norm(l, 1) * np.linalg.norm(got, 1)
+
+
+TRSM_BASE_T = 128
+TRSM_CASES = [(n, lower, unit, trans_a, left, "float64")
+              for n in (384, 320)  # whole base blocks; ragged
+              for lower in (True, False) for unit in (False, True)
+              for trans_a in (False, True) for left in (True, False)]
+TRSM_CASES.append((384, True, False, True, False, "complex64"))
+
+
+@pytest.mark.parametrize("n, lower, unit, trans_a, left, dtype",
+                         TRSM_CASES)
+def test_trsm_rec_matches_solve_triangular(n, lower, unit, trans_a, left,
+                                           dtype):
+    """trsm_rec against jax.scipy's solve_triangular, on both of its
+    paths: the sweep's diagonal blocks inverted up front in one batch
+    (n a multiple of base) and each leaf inverting its own (ragged n).
+    The other triangle holds garbage and, for unit, the stored diagonal
+    is not one: neither may be read."""
+    from jax.scipy.linalg import solve_triangular
+    from slate_tpu.ops import blocked
+
+    rng = np.random.default_rng(n + 2 * lower + 4 * unit + 8 * trans_a
+                                + 16 * left)
+    a = rng.standard_normal((n, n)) / np.sqrt(n)
+    if dtype == "complex64":
+        a = a + 1j * rng.standard_normal((n, n)) / np.sqrt(n)
+    a[np.arange(n), np.arange(n)] = 2.0 + np.abs(a.diagonal())
+    b = rng.standard_normal((n, 16) if left else (16, n)).astype(dtype)
+    a, b = jnp.asarray(a, dtype), jnp.asarray(b)
+    got = blocked.trsm_rec(a, b, left=left, lower=lower, unit=unit,
+                           trans_a=trans_a, base=TRSM_BASE_T)
+    # X·op(A) = B  ⇔  op(A)ᵀ·Xᵀ = Bᵀ
+    trans = int(trans_a) if left else int(not trans_a)
+    want = solve_triangular(a, b if left else b.T, trans=trans,
+                            lower=lower, unit_diagonal=unit)
+    want = np.asarray(want if left else want.T)
+    tol = 1e-4 if dtype == "complex64" else 1e-12
+    assert np.abs(np.asarray(got) - want).max() <= tol * np.abs(want).max()
+
+
+def _trsm_hlo(n, lower):
+    from slate_tpu.ops import blocked
+
+    def sweep(a, b):
+        return blocked.trsm_rec(a, b, lower=lower, base=TRSM_BASE_T)
+
+    return jax.jit(sweep).lower(
+        jax.ShapeDtypeStruct((n, n), jnp.float64),
+        jax.ShapeDtypeStruct((n, 16), jnp.float64)).as_text(
+            dialect="hlo", debug_info=True)
+
+
+@pytest.mark.parametrize("lower", [True, False])
+def test_trsm_rec_inverts_its_diagonal_blocks_in_one_batch(lower):
+    """At n = 4·base a sweep lowers no loop: its four diagonal blocks are
+    inverted by ONE leaf kernel under ``trsm_diag_inv`` — 64 row
+    substitutions (one divide each), each over all 4·(base/64) leaves."""
+    from slate_tpu.ops import blocked
+
+    n = 4 * TRSM_BASE_T
+    text = _trsm_hlo(n, lower)
+    assert not re.search(r"\bwhile\(", text)
+    divides = [ln for ln in text.splitlines() if " divide(" in ln]
+    assert divides and all("/trsm_diag_inv/" in ln for ln in divides)
+    assert len(divides) == blocked.TRTRI_BASE
+    leaves = 4 * TRSM_BASE_T // blocked.TRTRI_BASE
+    assert all(f"f64[{leaves},{blocked.TRTRI_BASE}]" in ln for ln in divides)
+
+
+def test_trsm_rec_ragged_sweep_inverts_leaf_by_leaf():
+    """A ragged n (3.5 base blocks) keeps each leaf's own inverse: the
+    fori_loop substitutions, and no ``trsm_diag_inv``."""
+    text = _trsm_hlo(7 * TRSM_BASE_T // 2, True)
+    assert re.search(r"\bwhile\(", text)
+    assert "trsm_diag_inv" not in text
