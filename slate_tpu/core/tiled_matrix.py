@@ -464,7 +464,11 @@ def unit_pad_diag(a: jax.Array, m_log: int, n_log: int) -> jax.Array:
     """Set 1 on the diagonal of the padding region (rows/cols beyond the
     logical (m_log, n_log)). The single shared helper behind every
     factorization's 'padded system is block-diag [[A,0],[0,I]]' trick
-    (SURVEY §7 risk (v))."""
+    (SURVEY §7 risk (v)). Without padding on the diagonal (static
+    shapes) a is returned as it is: rewriting its diagonal onto itself
+    would still cost a full-size copy."""
+    if min(a.shape) <= min(m_log, n_log):
+        return a
     idx = jnp.arange(min(a.shape))
     d = jnp.diagonal(a)[: idx.size]
     on_pad = (idx >= m_log) | (idx >= n_log)

@@ -40,8 +40,8 @@ from ..core.exceptions import SlateError
 from ..core.grid import COL_AXIS, ROW_AXIS
 from ..core.tiled_matrix import (TiledMatrix, from_dense,
                                  unit_pad_diag)
-from ..core.types import (Diag, MatrixKind, MethodGemm, Options, Side, Uplo,
-                          DEFAULT_OPTIONS)
+from ..core.types import (Diag, MatrixKind, MethodGemm, Op, Options, Side,
+                          Uplo, DEFAULT_OPTIONS)
 from ..ops import blocked, tile_ops
 
 
@@ -315,16 +315,29 @@ def trsm(side: Side, alpha, A: TiledMatrix, B: TiledMatrix,
     triangular_solve is latency-bound and ~5× below the gemm rate on TPU;
     the inverted-diagonal-block scheme matches what cuBLAS does for the
     reference). The padded diagonal is set to 1 so padding solves to
-    zero."""
+    zero.
+
+    A Triangular A is read as stored: both solvers read only its
+    referenced triangle (and not a unit diagonal), so no masked copy is
+    made, and a transposed view is solved through trans_a/conj_a on the
+    stored array. A TriangularBand A is masked first (full_dense): the
+    band mask defines that operand."""
     from ..core.types import MethodTrsm
     if A.kind not in (MatrixKind.Triangular, MatrixKind.TriangularBand):
         raise SlateError("trsm: A must be triangular")
-    uplo = A.uplo
-    if uplo is Uplo.General:
+    if A.uplo is Uplo.General:
         raise SlateError("trsm: A must have uplo Lower/Upper")
-    a = A.full_dense_canonical()
+    trans, conj = False, False
+    if A.kind is MatrixKind.TriangularBand:
+        S, a = A, A.full_dense_canonical()
+    else:
+        trans, conj = A.op is not Op.NoTrans, A.op is Op.ConjTrans
+        # the stored matrix: flipping the view back is metadata only
+        S = A.H if conj else A.T if trans else A
+        a = S.dense_canonical()
     # unit-pad the diagonal so the padded system is nonsingular
-    a = unit_pad_diag(a, A.shape[0], A.shape[1])
+    a = unit_pad_diag(a, S.shape[0], S.shape[1])
+    lower = S.uplo is Uplo.Lower
     b = B.dense_canonical()
     method = opts.method_trsm
     if method is MethodTrsm.B:
@@ -335,15 +348,14 @@ def trsm(side: Side, alpha, A: TiledMatrix, B: TiledMatrix,
         # B is kept for narrow rhs where substitution's lower flop
         # count can win over the inversion recursion.
         x = jax.lax.linalg.triangular_solve(
-            a, alpha * b, left_side=(side is Side.Left),
-            lower=(uplo is Uplo.Lower),
+            a, alpha * b, left_side=(side is Side.Left), lower=lower,
+            transpose_a=trans, conjugate_a=conj,
             unit_diagonal=(A.diag is Diag.Unit))
     else:
         x = blocked.trsm_rec(
             a, alpha * b,
-            left=(side is Side.Left),
-            lower=(uplo is Uplo.Lower),
-            unit=(A.diag is Diag.Unit),
+            left=(side is Side.Left), lower=lower,
+            unit=(A.diag is Diag.Unit), trans_a=trans, conj_a=conj,
             prec=opts.update_precision,
             base=min(A.nb, a.shape[0]))
     grid = _grid_of(B, A)

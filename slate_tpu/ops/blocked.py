@@ -309,40 +309,45 @@ def _diag_inverses(m: Array, lower: bool, unit: bool, base: int) -> Array:
         return _bT(trtri_lower_batched(_bT(d), unit))
 
 
-def _trsm_left_lower(m: Array, b: Array, unit: bool, prec, base,
-                     inv: Optional[Array], k: int) -> Array:
-    """X with M·X = B, M lower triangular (only lower triangle read).
-    ``inv[k:]`` are the inverses of M's base-size diagonal blocks (None:
-    each leaf inverts its own block)."""
+def _op(x: Array, trans: bool, conj: bool) -> Array:
+    """x, xᵀ, conj(x) or xᴴ of a block: the transpose of a slice that
+    feeds a dot folds into the dot's contraction dimensions."""
+    if conj:
+        x = jnp.conj(x)
+    return x.T if trans else x
+
+
+def _trsm_sweep(m: Array, b: Array, lower: bool, unit: bool, trans: bool,
+                conj: bool, prec, base, inv: Optional[Array],
+                k: int) -> Array:
+    """X with op(M)·X = B, M triangular as stored (lower if ``lower``).
+    op(M)'s diagonal blocks are op of M's, and its off-diagonal block is
+    op of M's one strictly-triangular block; a sweep runs forward where
+    op(M) is lower. ``inv[k:]`` are the inverses of M's base-size
+    diagonal blocks (None: each leaf inverts its own)."""
     n = m.shape[0]
     if n <= base:
-        return mm(trtri_lower_rec(m, unit) if inv is None else inv[k],
-                  b, prec)
+        d = inv[k] if inv is not None else trtri_rec(m, lower, unit)
+        return mm(_op(d, trans, conj), b, prec)
     h = _half(n, base)
-    x1 = _trsm_left_lower(m[:h, :h], b[:h], unit, prec, base, inv, k)
-    rhs2 = b[h:] - mm(m[h:, :h], x1, prec)
-    x2 = _trsm_left_lower(m[h:, h:], rhs2, unit, prec, base, inv,
-                          k + h // base)
+    kh = k + h // base
+    off = _op(m[h:, :h] if lower else m[:h, h:], trans, conj)
+    if lower != trans:  # op(M) lower: forward
+        x1 = _trsm_sweep(m[:h, :h], b[:h], lower, unit, trans, conj, prec,
+                         base, inv, k)
+        x2 = _trsm_sweep(m[h:, h:], b[h:] - mm(off, x1, prec), lower, unit,
+                         trans, conj, prec, base, inv, kh)
+    else:
+        x2 = _trsm_sweep(m[h:, h:], b[h:], lower, unit, trans, conj, prec,
+                         base, inv, kh)
+        x1 = _trsm_sweep(m[:h, :h], b[:h] - mm(off, x2, prec), lower, unit,
+                         trans, conj, prec, base, inv, k)
     return jnp.concatenate([x1, x2], axis=0)
 
 
-def _trsm_left_upper(m: Array, b: Array, unit: bool, prec, base,
-                     inv: Optional[Array], k: int) -> Array:
-    n = m.shape[0]
-    if n <= base:
-        return mm(trtri_rec(m, lower=False, unit=unit) if inv is None
-                  else inv[k], b, prec)
-    h = _half(n, base)
-    x2 = _trsm_left_upper(m[h:, h:], b[h:], unit, prec, base, inv,
-                          k + h // base)
-    rhs1 = b[:h] - mm(m[:h, h:], x2, prec)
-    x1 = _trsm_left_upper(m[:h, :h], rhs1, unit, prec, base, inv, k)
-    return jnp.concatenate([x1, x2], axis=0)
-
-
-def _trsm_left(m: Array, b: Array, lower: bool, unit: bool, prec,
-               base: int) -> Array:
-    """X with M·X = B, M triangular. Where M is whole base-size blocks
+def _trsm_left(m: Array, b: Array, lower: bool, unit: bool, trans: bool,
+               conj: bool, prec, base: int) -> Array:
+    """X with op(M)·X = B, M triangular. Where M is whole base-size blocks
     and base a size trtri_lower_batched batches, the sweep's diagonal
     blocks are inverted up front in one call; else each leaf of the
     recursion inverts its own (ragged n, base ≤ TRTRI_BASE)."""
@@ -350,30 +355,28 @@ def _trsm_left(m: Array, b: Array, lower: bool, unit: bool, prec,
     inv = None
     if n >= base and n % base == 0 and _pow2_leaves(base, TRTRI_BASE):
         inv = _diag_inverses(m, lower, unit, base)
-    sweep = _trsm_left_lower if lower else _trsm_left_upper
-    return sweep(m, b, unit, prec, base, inv, 0)
+    return _trsm_sweep(m, b, lower, unit, trans, conj, prec, base, inv, 0)
 
 
 def trsm_rec(a: Array, b: Array, *, left: bool = True, lower: bool = True,
              unit: bool = False, trans_a: bool = False,
              conj_a: bool = False, prec: Optional[str] = None,
              base: int = TRSM_BASE) -> Array:
-    """Solve op(A)·X = B (left) or X·op(A) = B (right), A triangular.
+    """Solve op(A)·X = B (left) or X·op(A) = B (right), A triangular and
+    op(A) = A, Aᵀ (trans_a), conj(A) (conj_a) or Aᴴ (both).
 
     Gemm-based replacement for lax.linalg.triangular_solve (see module
-    docstring for why). op(A) is materialized first (XLA fuses the
-    transpose/conj into the consumers)."""
-    m = a
-    if conj_a:
-        m = jnp.conj(m)
-    eff_lower = lower
-    if trans_a:
-        m = m.T
-        eff_lower = not lower
+    docstring for why). Reads only A's referenced triangle — the lower
+    one if ``lower``, without the diagonal if ``unit`` — so the other
+    triangle, and a unit diagonal, may hold anything. op(A) is never
+    formed: a transposed solve reads A's blocks as stored and contracts
+    over their rows."""
     if left:
-        return _trsm_left(m, b, eff_lower, unit, prec, base)
-    # right: X·M = B  ⇔  Mᵀ·Xᵀ = Bᵀ
-    return _trsm_left(m.T, b.T, not eff_lower, unit, prec, base).T
+        return _trsm_left(a, b, lower, unit, trans_a, conj_a, prec, base)
+    # right: X·op(A) = B  ⇔  op(A)ᵀ·Xᵀ = Bᵀ, and op(A)ᵀ is A's other
+    # transpose with the same conjugation
+    return _trsm_left(a, b.T, lower, unit, not trans_a, conj_a, prec,
+                      base).T
 
 
 # ---------------------------------------------------------------------------
