@@ -1,8 +1,8 @@
 """Process-level JAX setup shared by the program entry points.
 
 ``enable_compile_cache`` places JAX's persistent compilation cache for
-the entry points that drive the chip (chip_smoke.py, bench.py,
-bench_serve.py, the tester CLI); the library itself never turns the
+the entry points that drive the chip (chip_smoke.py, bench_serve.py,
+the tester CLI); the library itself never turns the
 cache on, and neither do the tests. ``xla_flag_supported`` probes
 whether this jaxlib accepts an XLA flag before anyone sets it.
 """

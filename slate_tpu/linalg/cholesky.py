@@ -137,19 +137,10 @@ def _potrf_rec(a: jax.Array, nb: int, prec, lookahead: int = 1):
     return out, info
 
 
-# On-chip crossover between the iterative right-looking loop and the
-# 2×2 recursion (round-5 A/B, tools/potrf_ab.py): below this size the
-# loop's single batched-leaf inverse per panel wins on latency; above
-# it the round-5 loop's trailing-block re-traffic (herk_lower_rec's
-# per-level concatenation copies) lost to the recursion's O(n² log nt)
-# touch pattern (PERF_HISTORY.md round 5). Round 6: the crossover only
-# gates the RECURSION's base case (the legacy dispatch,
-# Options.factor_iter_large=False) — the default dispatch runs the
-# iterative loop at ALL sizes with nt ≤ _ITER_MAX_NT, because its
-# trailing update is now written in place slab-by-slab
-# (blocked.herk_trailing_inplace: no concatenation copies, the lower
-# trapezoid touched once per step) with the Pallas chol_tile kernel as
-# the diagonal base at every step.
+# The size at which the recursion (nt > _ITER_MAX_NT only) hands a
+# block to the iterative loop: the round-5 on-chip crossover between
+# the two, below which the loop's single batched-leaf inverse per
+# panel won on latency (PERF_HISTORY.md round 5).
 _POTRF_ITER_BASE = 2048
 # HLO-size guard for the unrolled loop (the crossover was measured at
 # nb=1024 → nt=2; small nb would otherwise unroll 128+ panel steps;
@@ -247,29 +238,26 @@ def _potrf_iter(a: jax.Array, nb: int, prec, lookahead: int = 1):
 
 
 def _potrf_blocked(a: jax.Array, nb: int, nt: int, prec: str = "high",
-                   iter_large: bool = True, lookahead: int = 1):
+                   lookahead: int = 1):
     """Blocked Cholesky on padded dense (lower) → (tril factor, info).
 
-    Dispatch (round 6): the in-place iterative loop owns EVERY size
-    with nt ≤ _ITER_MAX_NT. The round-5 crossover (_POTRF_ITER_BASE,
-    on-chip A/B tools/potrf_ab.py) was set by the loop's trailing
-    re-traffic — herk_lower_rec's per-level concatenation copies, 131
-    ms of a 200 ms n=16384 call — which the slab-wise in-place update
+    Dispatch, from the shape alone: the in-place iterative loop owns
+    EVERY size with nt ≤ _ITER_MAX_NT. The round-5 crossover
+    (_POTRF_ITER_BASE) was set by the loop's trailing re-traffic —
+    herk_lower_rec's per-level concatenation copies, 131 ms of a 200
+    ms n=16384 call — which the slab-wise in-place update
     (blocked.herk_trailing_inplace) removes; what remains is
     right-looking's inherent once-per-step trailing write, an
     O(n³/(3nb)) HBM term (~11 GB ≈ one-digit ms at n=16384 nb=1024 on
-    v5e). The 2×2 recursion remains for nt > _ITER_MAX_NT (HLO-size
-    guard) and as the legacy dispatch (Options.factor_iter_large=False
-    — the round-5 policy, iterative only below the crossover), which
-    is also the reassociation-tolerance reference arm for tests.
+    v5e). The 2×2 recursion takes nt > _ITER_MAX_NT, the HLO-size
+    guard of the unrolled loop.
 
-    ``lookahead`` (round 7, Options.lookahead): ≥ 1 runs the iterative
-    loop as the lookahead pipeline (panel k+1 factored between the
-    next-panel slab and the remainder slabs of trailing update k —
-    bit-identical, schedule-decoupled); 0 restores the strictly
-    sequential round-6 schedule (the tolerance/HLO reference arm)."""
+    ``lookahead`` (Options.lookahead): ≥ 1 runs the iterative loop as
+    the lookahead pipeline (panel k+1 factored between the next-panel
+    slab and the remainder slabs of trailing update k — bit-identical,
+    schedule-decoupled); 0 is the strictly sequential schedule."""
     s = a.shape[0]
-    if iter_large and _iter_eligible(s, nb):
+    if _iter_eligible(s, nb):
         out, info = _potrf_iter(a, nb, prec=prec, lookahead=lookahead)
     else:
         out, info = _potrf_rec(a, nb, prec=prec, lookahead=lookahead)
@@ -308,7 +296,6 @@ def potrf(A: TiledMatrix, opts: Options = DEFAULT_OPTIONS
     nt = A.mt
     with blocked.distribute_on(A.grid):
         lower, info = _potrf_blocked(a, nb, nt, prec=opts.update_precision,
-                                     iter_large=opts.factor_iter_large,
                                      lookahead=normalize_lookahead(
                                          opts.lookahead))
     with jax.named_scope("potrf_epilogue"):

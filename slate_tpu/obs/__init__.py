@@ -11,7 +11,7 @@ tools ingest:
 * :mod:`.export`     — Chrome-trace/Perfetto ``trace_event`` JSON
   (one lane per thread + one per phase class) with a schema validator.
 * :mod:`.flops`      — the FLOP ledger: every model-GFLOP formula in
-  one module (bench.py, tester.py, and runtime/session.py all import
+  one module (tester.py and runtime/session.py both import
   from here) plus the process-wide monotone flop counter the drivers
   credit.
 * :mod:`.exposition` — Prometheus text rendering of runtime Metrics +

@@ -28,8 +28,8 @@ is HBM- and ICI-bound, not flop-bound):
   roofline join (obs/roofline.py) divides the flop ledger by it.
 
 * :func:`call_analyzed` instruments the explicitly-scheduled mesh
-  drivers (parallel/summa.py, parallel/panel.py): first call per shape
-  AOT-lowers the jitted driver once for analysis (cached), every call
+  driver (parallel/summa.py): first call per shape AOT-lowers the
+  jitted driver once for analysis (cached), every call
   credits the ledger with the program's collective traffic — the
   telemetry the shard_map drivers never had.
 

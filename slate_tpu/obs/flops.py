@@ -1,12 +1,11 @@
 """FLOP/byte ledger: ONE home for the model-GFLOP formulas.
 
-Before this module the lawn41-convention flop models lived in three
-places — bench.py (gemm/potrf/getrf/geqrf/heev/svd headline rows),
-slate_tpu/tester.py (the ~40 ``register(..., flops=...)`` lambdas), and
-runtime/session.py (``_factor_flops``/``_solve_flops`` feeding the
-serving metrics) — three copies of the same numerator that could (and
-did) drift. They are all defined here once, in the reference tester's
-conventions (blas::Gflop as used by test/test_*.cc; lawn41 counts).
+The lawn41-convention flop models that slate_tpu/tester.py (the ~40
+``register(..., flops=...)`` lambdas) and runtime/session.py
+(``_factor_flops``/``_solve_flops`` feeding the serving metrics) use
+are defined here once, so the numerators cannot drift, in the
+reference tester's conventions (blas::Gflop as used by
+test/test_*.cc; lawn41 counts).
 
 The module also keeps a process-wide :class:`FlopLedger`: every
 simplified-API driver call (api.py) credits its model flops here, so

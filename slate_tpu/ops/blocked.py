@@ -88,12 +88,6 @@ def _single_device() -> bool:
     return _GRID_CTX.get() is None
 
 
-def current_grid():
-    """The grid installed by distribute_on (None outside a context) —
-    the public accessor; callers must not read _GRID_CTX directly."""
-    return _GRID_CTX.get()
-
-
 def rebalance(x: Array) -> Array:
     """Constrain a 2-D intermediate to the active grid's (p, q) spec —
     the per-level load-balancing resharding (see _GRID_CTX). No-op
@@ -395,21 +389,10 @@ def herk_lower_rec(c: Array, a: Array, b: Optional[Array] = None,
     full gemm), which is where the reference's internal::herk wins too
     (src/internal/internal_herk.cc).
 
-    The Pallas tile-triangle kernel (ops/pallas_ops.herk_lower_update)
-    is an OPT-IN alternative for the pure-herk case
-    (SLATE_TPU_PALLAS_HERK=1, single device, divisible shapes): round-3
-    A/B measurement showed it HBM-bound on tile re-reads and no faster
-    than this recursion end-to-end (PERF_HISTORY.md), so the jnp path is the
-    default. Multi-device grids always use the recursion (GSPMD cannot
-    partition a pallas_call, and rebalance() constraints live here)."""
+    A Pallas tile-triangle herk kernel measured HBM-bound on tile
+    re-reads and no faster than this recursion end to end (round 3,
+    PERF_HISTORY.md), so the jnp recursion is the path."""
     if b is None:
-        from . import pallas_ops
-        blk = pallas_ops.default_block(a.shape[1])
-        if _single_device() and pallas_ops.herk_eligible(
-                c.shape[0], a.shape[1], c.dtype, blk):
-            # kernel runs HIGHEST regardless of prec (see pallas_ops —
-            # it is HBM-bound, so the pass count doesn't matter)
-            return pallas_ops.herk_lower_update(c, a, blk)
         b = a
     s = c.shape[0]
     if s <= base:
@@ -546,7 +529,7 @@ def chol_tile_blocked(a: Array, ib: int = 64) -> Array:
         # fori_loop path below pays ~230 µs per ib-step in per-op
         # dispatch latency (64 sequential trtri matvecs, each its own
         # XLA op); in-kernel the same chain is pipeline-latency only
-        # (measured: PERF_HISTORY.md round 5, tools/potrf_ab.py)
+        # (measured: PERF_HISTORY.md round 5)
         return pallas_ops.chol_tile(a)
     if b % ib or b <= ib:
         if a.dtype in (jnp.bfloat16, jnp.float16):
@@ -639,12 +622,11 @@ def permute_rows_limited(x: Array, perm: Array, max_moved: int) -> Array:
     in the signature as documentation of the displacement bound (and
     for any future backend where bounded scatter wins).
 
-    Round 6: the DEFAULT getrf/getrf_tntpiv paths no longer call this
-    per level at all — the permutation is folded into the trailing
-    update's row reads (pivot fusion, linalg/lu.py) and the stored L
-    columns are reordered once at the end. This materialized permute
-    remains in the recursion (_getrf_rec), the legacy arm
-    (Options.lu_pivot_fusion=False), and the wide-matrix rest solve."""
+    The iterative getrf/getrf_tntpiv loops never call this per level
+    — the permutation is folded into the trailing update's row reads
+    (pivot fusion, linalg/lu.py) and the stored L columns are
+    reordered once at the end. This materialized permute remains in
+    the recursion (_getrf_rec) and the wide-matrix rest solve."""
     del max_moved
     with jax.named_scope("row_swap"):
         return x[perm]

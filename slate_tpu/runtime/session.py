@@ -57,7 +57,7 @@ from ..core.tiled_matrix import TiledMatrix, from_dense
 from ..core.types import MatrixKind, Options, DEFAULT_OPTIONS
 from ..linalg.band_packed import PackedBand
 # model-GFLOP formulas live in the ledger (obs/flops.py) — one home
-# shared with bench.py and tester.py instead of a private copy here
+# shared with tester.py instead of a private copy here
 from ..obs import flops as _flops_mod
 from ..obs.flops import LEDGER as _LEDGER
 from ..obs.flops import factor_flops as _ff_raw

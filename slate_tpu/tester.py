@@ -45,7 +45,7 @@ import numpy as np
 DEFAULT_TOL = 3.0
 
 # model-GFLOP formulas come from the central FLOP ledger (obs/flops.py
-# — shared with bench.py and runtime/session.py); `_fl(name)` is the
+# — shared with runtime/session.py); `_fl(name)` is the
 # tester's (m, n) signature over that table, `_fl2` adapts ad-hoc rows
 from .obs.flops import tester_model as _fl
 
@@ -100,11 +100,9 @@ class Ctx:
         batch end, best of two reps each; the per-call time is the
         slope (t₂ₖ − tₖ)/K, so the one-time dispatch/sync round-trip —
         ~1 s per fetch on the remote-TPU setup of rounds 2–5, the term
-        that made that setup's sweep rows ~100× below bench.py steady
-        state — cancels and the GFLOP/s column is steady-state. The
-        implementation (shared with bench.py's heev/svd rows so the
-        floor/sync idioms cannot drift) is
-        utils/timing.eager_slope_seconds."""
+        that made that setup's sweep rows ~100× below steady state —
+        cancels and the GFLOP/s column is steady-state. The
+        implementation is utils/timing.eager_slope_seconds."""
         from slate_tpu.utils.timing import eager_slope_seconds
 
         if self.iters <= 1:
@@ -1753,15 +1751,6 @@ def _t_gesv_calu(ctx):
     return _lu_solver_case(
         ctx, lambda st, A, B: st.gesv(A, B,
                                       Options(method_lu=MethodLU.CALU))[0])
-
-
-@register("gesv_dist_panel", flops=_fl("gesv"))
-def _t_gesv_dist_panel(ctx):
-    """lu_dist_panel: the explicit shard_map distributed-panel path."""
-    from slate_tpu.core.types import Options
-    return _lu_solver_case(
-        ctx, lambda st, A, B: st.gesv(A, B,
-                                      Options(lu_dist_panel=True))[0])
 
 
 @register("gesv_threshold", flops=_fl("gesv"), tol=30)

@@ -7,7 +7,7 @@ space per (op, pow2-n-bucket, dtype, platform): for the dense drivers
 ``wide_panel`` — and for the small-problem engine (lu_small/chol_small)
 it sweeps (nb, batch/width bucket quantum). Each candidate is
 AOT-compiled ONCE (``jit(...).lower(...).compile()``, compiles counted)
-and slope-timed with the bench.py technique (time k1 then k2 executions;
+and slope-timed (time k1 then k2 executions;
 the per-iteration difference quotient cancels dispatch overhead), then
 scored by joining the measured seconds against the program's
 compile-time cost analysis through
@@ -40,8 +40,8 @@ DENSE_OPS = ("chol", "lu", "qr")
 SMALL_OPS = ("lu_small", "chol_small")
 DEFAULT_OPS = DENSE_OPS + SMALL_OPS
 
-# slope-timing iteration counts (bench.py's k1/k2 technique, smaller:
-# a search visits |space| × |cells| programs, a bench visits one)
+# slope-timing iteration counts (small: a search visits
+# |space| × |cells| programs)
 SLOPE_K1 = 2
 SLOPE_K2 = 6
 # live batch the small-engine candidates execute: deliberately off the
@@ -88,7 +88,7 @@ def config_space(op: str, n: int, quick: bool = False) -> List[dict]:
 
 def slope_seconds(call: Callable[[], None], k1: int = SLOPE_K1,
                   k2: int = SLOPE_K2, target_s: float = 0.02) -> float:
-    """Per-iteration seconds by the bench.py slope method: time k1
+    """Per-iteration seconds by the slope method: time k1
     executions, then k2, and return the difference quotient — constant
     dispatch overhead cancels. The iteration counts auto-scale so the
     first window spans ~``target_s`` (a µs-scale program slope-timed

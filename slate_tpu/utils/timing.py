@@ -1,7 +1,6 @@
-"""Warm-iteration slope timing (round 6) — ONE implementation shared
-by the two evidence producers that cannot wrap their subject in a jit
-scan: tester.Ctx.timed's ``--iters`` mode and bench.py's heev/svd rows
-(whose drivers route secular/deflation stages through the host).
+"""Warm-iteration slope timing (round 6) for a subject that cannot be
+wrapped in a jit scan: tester.Ctx.timed's ``--iters`` mode (heev/svd
+drivers route secular/deflation stages through the host).
 
 Methodology: warm once, then time back-to-back batches of k1 and k2
 calls with ONE result fetch at each batch end — jax dispatch is async,

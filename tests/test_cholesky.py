@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import slate_tpu as st
-from slate_tpu.core.types import Norm, Options, Uplo
+from slate_tpu.core.types import Norm, Uplo
 from slate_tpu.matgen import random_spd
 
 RNG = np.random.default_rng(11)
@@ -139,15 +139,13 @@ def test_posv_mixed():
 @pytest.mark.slow  # ~8 s dispatch-policy probe (round-10 headroom);
 # potrf numerics and the fastpaths dispatch probes stay tier-1
 def test_potrf_rec_iter_base_dispatch(monkeypatch):
-    """Round-5 hybrid dispatch — now the LEGACY arm
-    (Options(factor_iter_large=False); the round-6 default routes every
-    nt ≤ 64 size straight to the in-place iterative loop): 2x2
-    recursion above the crossover, iterative loop as its base case.
-    With the crossover lowered to 64, n=128 must split once in
-    _potrf_rec and factor each 64-half with _potrf_iter."""
+    """The hybrid dispatch past the HLO-size guard: 2x2 recursion
+    above it, iterative loop as its base case. With the guard lowered
+    to nt = 4, n=128 at nb=16 (nt = 8) must split once in _potrf_rec
+    and factor each 64-half with _potrf_iter."""
     from slate_tpu.linalg import cholesky as chol_mod
 
-    monkeypatch.setattr(chol_mod, "_POTRF_ITER_BASE", 64)
+    monkeypatch.setattr(chol_mod, "_ITER_MAX_NT", 4)
     calls = {"iter": 0, "rec": 0}
     for name in ("_potrf_iter", "_potrf_rec"):
         orig = getattr(chol_mod, name)
@@ -159,10 +157,10 @@ def test_potrf_rec_iter_base_dispatch(monkeypatch):
 
         monkeypatch.setattr(chol_mod, name, spy)
 
-    n, nb = 128, 16  # 128 > 64 -> rec splits; 64-halves -> iter
+    n, nb = 128, 16  # nt 8 > 4 -> rec splits; 64-halves -> iter
     a = np.asarray(random_spd(n, dtype=jnp.float64, seed=77))
     A = st.hermitian(np.tril(a), nb=nb, uplo=Uplo.Lower)
-    L, info = st.potrf(A, Options(factor_iter_large=False))
+    L, info = st.potrf(A)
     assert int(info) == 0
     assert calls["rec"] >= 1 and calls["iter"] == 2
     assert _residual_factor(a, L) < 3.0
@@ -175,19 +173,19 @@ def test_potrf_rec_iter_base_dispatch(monkeypatch):
 def test_potrf_hybrid_info_offset(monkeypatch):
     """Non-SPD pivot inside the SECOND recursion half reports the
     correct absolute 1-based LAPACK info index through the hybrid
-    rec->iter dispatch — and identically through the round-6 default
-    (iterative in-place) dispatch."""
+    rec->iter dispatch — and identically through the iterative
+    in-place loop alone."""
     from slate_tpu.linalg import cholesky as chol_mod
 
-    monkeypatch.setattr(chol_mod, "_POTRF_ITER_BASE", 64)
     n, nb = 128, 16  # halves cover columns [0,64) [64,128)
     a = np.array(random_spd(n, dtype=jnp.float64, seed=79))
     bad = 100  # 0-based, inside the second half
     a[bad, bad] = -(abs(a).sum())  # dominate: leading minor fails there
     A = st.hermitian(np.tril(a), nb=nb, uplo=Uplo.Lower)
-    L, info = st.potrf(A, Options(factor_iter_large=False))
+    L, info = st.potrf(A)  # nt 8: the iterative in-place loop
     assert int(info) == bad + 1
-    L, info = st.potrf(A)  # round-6 default: iterative in-place loop
+    monkeypatch.setattr(chol_mod, "_ITER_MAX_NT", 4)  # rec -> iter
+    L, info = st.potrf(A)
     assert int(info) == bad + 1
 
 

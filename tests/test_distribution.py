@@ -121,7 +121,7 @@ def test_factorization_outputs_stay_sharded(grid2x4, routine):
     "potrf",
     # the getrf arm (~9 s) rides the slow lane (round-10 headroom):
     # mesh getrf stays pinned by the nb=64 perm-regression test and
-    # the fastpaths mesh pivot-fusion bit-identity test; the geqrf arm
+    # test_mesh_getrf_small; the geqrf arm
     # (~12 s, its own n=256 mesh factor compile) follows in round 22 —
     # mesh geqrf stays pinned by test_qr.py::test_geqrf_jit_and_grid
     pytest.param("getrf", marks=pytest.mark.slow),
@@ -234,63 +234,23 @@ def test_hlo_rank_k_family_has_collectives(grid2x4):
                                rtol=1e-12, atol=1e-12)
 
 
-def test_dist_panel_maxloc_small(grid2x4):
-    """Tier-1 sibling of test_dist_panel_maxloc (round-22 budget): the
-    same shard_map maxloc-panel contract — LU correctness under the
-    pivot collective + collectives present in the compiled HLO — on a
-    half-width panel (w=32 halves the unrolled column loop that
-    dominates the compile)."""
-    import jax.numpy as jnp
-    from slate_tpu.parallel.panel import dist_panel_getrf
-
-    rng = np.random.default_rng(22)
-    m, w = 256, 32
-    a = jnp.asarray(rng.standard_normal((m, w)))
-    lu, perm, info = dist_panel_getrf(a, grid2x4)
-    lu, perm = np.asarray(lu), np.asarray(perm)
+def test_mesh_getrf_small(grid2x4):
+    """Tier-1 sibling of the slow mesh getrf cases: getrf on the 2×4
+    mesh through the GSPMD panel (the panel operand replicated, XLA's
+    own collectives) at one (256, 32) panel — A[perm] = L·U against
+    numpy, and collectives present in the compiled program."""
+    m, w, nb = 256, 32, 32
+    a = np.random.default_rng(22).standard_normal((m, w))
+    A = st.from_dense(a, nb=nb, grid=grid2x4)
+    LU, perm, info = st.getrf(A)
     assert int(info) == 0
-    L = np.tril(lu, -1) + np.concatenate(
-        [np.eye(w), np.zeros((m - w, w))])
+    lu, perm = LU.to_numpy(), np.asarray(perm)
+    assert np.array_equal(np.sort(perm), np.arange(len(perm)))
+    L = np.tril(lu, -1) + np.eye(m, w)
     U = np.triu(lu[:w])
-    assert np.abs(np.asarray(a)[perm] - L @ U).max() < 1e-12
-    assert _collective_count(lambda x: dist_panel_getrf(x, grid2x4),
-                             a) > 0, \
-        "maxloc panel compiled without collectives"
-
-
-@pytest.mark.slow  # ~11 s: the w=64 panel compile + the n=256 mesh
-# getrf driver-site agreement are each their own compiles (round-22
-# tier-1 budget); tier-1 sibling test_dist_panel_maxloc_small keeps
-# the maxloc-panel contract in tier-1
-def test_dist_panel_maxloc(grid2x4):
-    """VERDICT r3 #7: the explicit shard_map panel (per-column maxloc
-    pivot collective + masked-psum row swaps, parallel/panel.py) must
-    match the GSPMD panel and compile with collectives; getrf routes to
-    it under Options.lu_dist_panel."""
-    import jax.numpy as jnp
-    from slate_tpu.parallel.panel import dist_panel_getrf
-
-    rng = np.random.default_rng(21)
-    m, w = 512, 64
-    a = jnp.asarray(rng.standard_normal((m, w)))
-    lu, perm, info = dist_panel_getrf(a, grid2x4)
-    lu, perm = np.asarray(lu), np.asarray(perm)
-    assert int(info) == 0
-    L = np.tril(lu, -1) + np.concatenate(
-        [np.eye(w), np.zeros((m - w, w))])
-    U = np.triu(lu[:w])
-    assert np.abs(np.asarray(a)[perm] - L @ U).max() < 1e-12
-
-    assert _collective_count(lambda x: dist_panel_getrf(x, grid2x4),
-                             a) > 0, \
-        "maxloc panel compiled without collectives"
-
-    # driver call site: getrf(lu_dist_panel=True) agrees with default
-    n, nb = 256, 32
-    A = st.from_dense(rng.standard_normal((n, n)), nb=nb, grid=grid2x4)
-    lu0 = st.getrf(A)[0].to_numpy()
-    lu1 = st.getrf(A, st.Options(lu_dist_panel=True))[0].to_numpy()
-    np.testing.assert_allclose(lu1, lu0, rtol=1e-10, atol=1e-10)
+    assert np.abs(a[perm[:m]] - L @ U).max() < 1e-12
+    assert _collective_count(lambda A: st.getrf(A)[0].data, A) > 0, \
+        "mesh getrf compiled without collectives"
 
 
 # -- P3 static evidence: scheduled-HLO collective/compute interleaving ------

@@ -1,13 +1,12 @@
 """Round-6 factorization fast-path tests.
 
-Covers the two composed mechanisms of the round-6 rework (ISSUE 2):
+Covers the two composed mechanisms of the round-6 rework:
 
 (a) PIVOT-FUSED LU trailing updates — the per-level row permutation is
     folded into the trailing-update gemm reads (gather-as-you-read +
     deferred left swaps, linalg/lu.py) instead of materializing a
-    full-width permuted copy per level. Guarded here by bit-level
-    equivalence against the materialized-copy reference arm
-    (Options(lu_pivot_fusion=False)) across dtypes and the 8-device
+    full-width permuted copy per level. Guarded here by the LU contract
+    against a float64 numpy reference across dtypes and the 8-device
     mesh, and by an HLO-level assertion that NO gather in the lowered
     program materializes a full-width row block.
 
@@ -17,11 +16,11 @@ Covers the two composed mechanisms of the round-6 rework (ISSUE 2):
     concatenation copies, with the Pallas tile/panel kernels as the
     base at every step. Guarded by dispatch-policy probes (the
     n=16384/nb=1024 headline shape must route to the iterative loop
-    without compiling anything), HLO assertions (dynamic-update-slice
-    present, no full-matrix concatenate), reassociation-tolerance
-    parity against the legacy 2×2 recursion, and a wiring check that
-    the Pallas bases sit on the default dispatch when a TPU backend is
-    present.
+    without compiling anything; the 2×2 recursion takes nt beyond the
+    HLO-size guard), HLO assertions (dynamic-update-slice present, no
+    full-matrix concatenate), reassociation-tolerance parity against
+    the recursion, and a wiring check that the Pallas bases sit on the
+    default dispatch when a TPU backend is present.
 """
 
 import re
@@ -40,8 +39,6 @@ from slate_tpu.ops import blocked, pallas_ops
 
 RNG = np.random.default_rng(61)
 
-_LEGACY = Options(lu_pivot_fusion=False)
-
 
 def _randn(m, n, dtype):
     a = RNG.standard_normal((m, n))
@@ -50,49 +47,68 @@ def _randn(m, n, dtype):
     return np.asarray(a, dtype)
 
 
-# -- (a) pivot fusion: bit-level equivalence --------------------------------
+def _check_lu(a, LU, perm, nb, bound=10.0):
+    """The getrf contract against a float64 numpy reference: ``perm`` is
+    a permutation, ‖A[perm] − L·U‖₁ / (n·ε·‖A‖₁) < ``bound``, and a
+    solve through the factors meets the tester's scaled residual
+    ‖b − A·x‖₁ / (n·ε·‖A‖₁·‖x‖₁) < 30, ε of the factor's dtype."""
+    n = a.shape[0]
+    p = np.asarray(perm)
+    assert np.array_equal(np.sort(p), np.arange(len(p)))
+    # padded rows never pivot into a logical column
+    assert np.array_equal(np.sort(p[:n]), np.arange(n))
+    a64 = a.astype(np.complex128)
+    lu = LU.to_numpy().astype(np.complex128)
+    lower = np.tril(lu, -1) + np.eye(n)
+    upper = np.triu(lu)
+    eps = np.finfo(a.dtype).eps
+    anorm = np.linalg.norm(a64, 1)
+    err = np.linalg.norm(a64[p[:n]] - lower @ upper, 1) / (n * eps * anorm)
+    assert err < bound, err
+    b = _randn(n, 3, a.dtype)
+    x = st.getrs(LU, perm, st.from_dense(b, nb=nb)).to_numpy()
+    x64 = x.astype(np.complex128)
+    res = (np.linalg.norm(b - a64 @ x64, 1, axis=0)
+           / (n * eps * anorm * np.linalg.norm(x64, 1, axis=0)))
+    assert res.max() < 30.0, res
+
+
+# -- (a) pivot fusion: the LU contract --------------------------------------
 
 @pytest.mark.parametrize("dtype,n,nb", [
     (np.float32, 96, 32),
     # the ragged 136 arm (~7 s, its own padded-shape compile) rides
     # the slow lane (round-22 tier-1 budget); ragged/pad isolation
-    # stays pinned by test_uneven_grid.py, and f32 fusion bit-identity
-    # by the 96 arm above
+    # stays pinned by test_uneven_grid.py
     pytest.param(np.float32, 136, 32, marks=pytest.mark.slow),
     (np.float64, 64, 32),  # 2 panels: trailing + suffix fix-up both hit
     (np.complex64, 64, 32), (np.complex128, 64, 32),
 ])
 def test_getrf_pivot_fusion_bit_identical(dtype, n, nb):
-    """Fused vs materialized must agree BIT FOR BIT: the fusion only
-    reorders row reads (gathers are exact) — every arithmetic op sees
-    the same values in the same order."""
+    """The fused factors (row permutation read inside the trailing
+    update, L columns reordered once at the end) satisfy A[perm] = L·U
+    and solve through getrs."""
     a = _randn(n, n, dtype)
-    A = st.from_dense(a, nb=nb)
-    LUf, pf, inf_f = st.getrf(A)
-    LUm, pm, inf_m = st.getrf(A, _LEGACY)
-    assert int(inf_f) == int(inf_m)
-    np.testing.assert_array_equal(np.asarray(pf), np.asarray(pm))
-    np.testing.assert_array_equal(np.asarray(LUf.data), np.asarray(LUm.data))
+    LU, perm, info = st.getrf(st.from_dense(a, nb=nb))
+    assert int(info) == 0
+    _check_lu(a, LU, perm, nb)
 
 
 @pytest.mark.parametrize("dtype", [
     # f32 arm (~10 s) rides the slow lane (round-10 headroom); the
     # f64 arm (~11 s) follows in round 22 — tntpiv numerics stay
-    # pinned by test_lu.py::test_getrf_tntpiv and pivot-fusion
-    # bit-identity by the plain-getrf f64 arm of
-    # test_getrf_pivot_fusion_bit_identical
+    # pinned by test_lu.py::test_getrf_tntpiv
     pytest.param(np.float32, marks=pytest.mark.slow),
     pytest.param(np.float64, marks=pytest.mark.slow)])
 def test_getrf_tntpiv_pivot_fusion_bit_identical(dtype):
-    """Same guarantee for the CALU/tournament driver."""
+    """Same contract for the CALU/tournament driver (tournament growth
+    is weaker than partial pivoting's: a looser factor bound)."""
     n, nb = 128, 32
     a = _randn(n, n, dtype)
-    A = st.from_dense(a, nb=nb)
-    calu = Options(method_lu=MethodLU.CALU)
-    LUf, pf, _ = st.getrf(A, calu)
-    LUm, pm, _ = st.getrf(A, calu.replace(lu_pivot_fusion=False))
-    np.testing.assert_array_equal(np.asarray(pf), np.asarray(pm))
-    np.testing.assert_array_equal(np.asarray(LUf.data), np.asarray(LUm.data))
+    LU, perm, info = st.getrf(st.from_dense(a, nb=nb),
+                              Options(method_lu=MethodLU.CALU))
+    assert int(info) == 0
+    _check_lu(a, LU, perm, nb, bound=100.0)
 
 
 def test_getrf_threshold_pivot_fusion_bit_identical():
@@ -100,25 +116,21 @@ def test_getrf_threshold_pivot_fusion_bit_identical():
     iterative loop."""
     n, nb = 96, 32
     a = _randn(n, n, np.float64)
-    A = st.from_dense(a, nb=nb)
-    thr = Options(pivot_threshold=0.5)
-    LUf, pf, _ = st.getrf(A, thr)
-    LUm, pm, _ = st.getrf(A, thr.replace(lu_pivot_fusion=False))
-    np.testing.assert_array_equal(np.asarray(pf), np.asarray(pm))
-    np.testing.assert_array_equal(np.asarray(LUf.data), np.asarray(LUm.data))
+    LU, perm, info = st.getrf(st.from_dense(a, nb=nb),
+                              Options(pivot_threshold=0.5))
+    assert int(info) == 0
+    _check_lu(a, LU, perm, nb, bound=100.0)
 
 
 def test_gesv_getrs_through_fused_factors():
     """getrs/gesv threaded through the fused factors solve correctly
-    and identically to the materialized arm (the b[perm] gather of
-    getrs reads the SAME total permutation either way)."""
+    (the b[perm] gather of getrs reads the total permutation)."""
     n, nb, nrhs = 128, 32, 4
     a = _randn(n, n, np.float64)
     b = _randn(n, nrhs, np.float64)
     A, B = st.from_dense(a, nb=nb), st.from_dense(b, nb=nb)
     Xf, inf_f = st.gesv(A, B)
-    Xm, inf_m = st.gesv(A, B, _LEGACY)
-    np.testing.assert_array_equal(np.asarray(Xf.data), np.asarray(Xm.data))
+    assert int(inf_f) == 0
     res = np.abs(a @ np.asarray(Xf.to_numpy()) - b).max() / (
         np.linalg.norm(a, 1) * np.finfo(np.float64).eps * n)
     assert res < 30.0
@@ -132,25 +144,17 @@ def test_gesv_getrs_through_fused_factors():
 
 @pytest.mark.slow
 def test_getrf_pivot_fusion_bit_identical_mesh(grid2x4):
-    """Bit-level equivalence must survive the 8-device mesh (the
+    """The fused factors keep the LU contract on the 8-device mesh (the
     deferred-left-swap suffix gathers become collective traffic there),
-    and the mesh result must match the 1×1 grid. Slow (round-20 tier-1
-    budget: two n=256 8-device factor compiles). Tier-1 siblings: the
-    single-device pivot-fusion bit-identity params above, and
-    test_distribution.py::test_grid_matches_single_device[getrf] for
-    mesh-getrf agreement."""
-    # nb=32 keeps this test on the round-6 shape; the (256, nb=64)
-    # corruption recorded here as an open item was ROOT-CAUSED AND
-    # FIXED in round 7 (two pre-0.6 partitioner mis-lowerings:
-    # blocked.lift_tail_perm + blocked.replicate_on_grid) and is
-    # regression-pinned at nb=64 in tests/test_lookahead.py.
+    and the mesh result matches the 1×1 grid. Slow (round-20 tier-1
+    budget: two n=256 8-device factor compiles). Tier-1 siblings:
+    test_distribution.py::test_mesh_getrf_small and
+    test_grid_matches_single_device[getrf]."""
     n, nb = 256, 32
     a = _randn(n, n, np.float64)
-    Ag = st.from_dense(a, nb=nb, grid=grid2x4)
-    LUf, pf, _ = st.getrf(Ag)
-    LUm, pm, _ = st.getrf(Ag, _LEGACY)
-    np.testing.assert_array_equal(np.asarray(pf), np.asarray(pm))
-    np.testing.assert_array_equal(np.asarray(LUf.data), np.asarray(LUm.data))
+    LUf, pf, info = st.getrf(st.from_dense(a, nb=nb, grid=grid2x4))
+    assert int(info) == 0
+    _check_lu(a, LUf, pf, nb)
     LU1, p1, _ = st.getrf(st.from_dense(a, nb=nb))
     np.testing.assert_array_equal(np.asarray(pf), np.asarray(p1))
     np.testing.assert_allclose(np.asarray(LUf.to_numpy()),
@@ -182,30 +186,25 @@ def _fullwidth_gather_count(opts, n=192, nb=64):
 
 
 def test_hlo_getrf_fused_has_no_fullwidth_permuted_copy():
-    """THE traffic assertion of ISSUE 2(a): the default getrf's
-    per-level trailing update contains NO materialized full-width
-    permuted row block — its gathers are the nb-row pivot read, the
-    (n−k1)-wide trailing read fused into the Schur subtract, and the
-    nb-wide deferred-left-swap blocks. The legacy arm (the same
-    program with lu_pivot_fusion=False) must show the per-level
-    full-width gather, proving the probe detects what it claims to."""
+    """The traffic assertion of pivot fusion: getrf's per-level
+    trailing update contains NO materialized full-width permuted row
+    block — its gathers are the nb-row pivot read, the (n−k1)-wide
+    trailing read fused into the Schur subtract, and the nb-wide
+    deferred-left-swap blocks."""
     assert _fullwidth_gather_count(Options()) == 0
-    assert _fullwidth_gather_count(_LEGACY) >= 1
 
 
 def test_hlo_getrf_tntpiv_fused_has_no_fullwidth_permuted_copy():
     assert _fullwidth_gather_count(Options(method_lu=MethodLU.CALU)) == 0
-    assert _fullwidth_gather_count(
-        Options(method_lu=MethodLU.CALU, lu_pivot_fusion=False)) >= 1
 
 
 # -- (b) in-place iterative outer loops -------------------------------------
 
 def test_iter_dispatch_policy_covers_headline_shapes():
-    """The round-6 dispatch must route the BENCH headline shapes
-    (n=16384, nb=1024 — and every nt ≤ 64 shape) to the iterative
-    in-place loop; the recursion survives only past the HLO-size guard.
-    Pure policy probe: nothing is compiled."""
+    """The dispatch must route the benchmark's shapes (n=16384,
+    nb=1024 — and every nt ≤ 64 shape) to the iterative in-place loop;
+    the recursion takes only nt past the HLO-size guard. Pure policy
+    probe: nothing is compiled."""
     assert chol_mod._iter_eligible(16384, 1024)
     assert lu_mod._iter_eligible(16384, 1024)
     assert chol_mod._iter_eligible(65536, 1024)   # nt = 64, boundary
@@ -213,49 +212,91 @@ def test_iter_dispatch_policy_covers_headline_shapes():
     assert not lu_mod._iter_eligible(16384 + 512, 1024)  # ragged width
 
 
-def test_potrf_dispatch_routes_to_iter_by_default(monkeypatch):
-    calls = {"iter": 0, "rec": 0}
-    for name in ("_potrf_iter", "_potrf_rec"):
-        orig = getattr(chol_mod, name)
+def _spy_routes(monkeypatch, mod, names):
+    """Count calls of each of ``mod``'s driver functions ``names``,
+    keyed by the name's last word (iter / rec)."""
+    calls = {name.split("_")[-1]: 0 for name in names}
+    for name in names:
+        orig = getattr(mod, name)
         key = name.split("_")[-1]
 
         def spy(*a, _o=orig, _k=key, **kw):
             calls[_k] += 1
             return _o(*a, **kw)
 
-        monkeypatch.setattr(chol_mod, name, spy)
+        monkeypatch.setattr(mod, name, spy)
+    return calls
+
+
+def test_potrf_dispatch_routes_to_iter_by_default(monkeypatch):
+    calls = _spy_routes(monkeypatch, chol_mod, ("_potrf_iter", "_potrf_rec"))
     a = np.asarray(random_spd(192, dtype=jnp.float64, seed=5))
     A = st.hermitian(np.tril(a), nb=64, uplo=Uplo.Lower)
     st.potrf(A)
     assert calls["iter"] == 1 and calls["rec"] == 0
-    st.potrf(A, Options(factor_iter_large=False))
+    # nt = 3 past the HLO-size guard: the recursion takes it
+    monkeypatch.setattr(chol_mod, "_ITER_MAX_NT", 2)
+    st.potrf(A)
     assert calls["rec"] >= 1
+
+
+@pytest.mark.parametrize("driver", ["potrf", "getrf"])
+@pytest.mark.parametrize("past_guard", [False, True],
+                         ids=["nt=max", "nt=max+1"])
+def test_factor_dispatch_boundary(monkeypatch, driver, past_guard):
+    """The one selection left in each driver, on both sides of it:
+    with the HLO-size guard _ITER_MAX_NT at 4, nt = 4 runs the
+    iterative loop alone and nt = 5 the 2×2 recursion (whose halves
+    the loop then factors); either way the factor matches numpy."""
+    nb, max_nt = 16, 4
+    n = nb * (max_nt + past_guard)
+    mod = chol_mod if driver == "potrf" else lu_mod
+    monkeypatch.setattr(mod, "_ITER_MAX_NT", max_nt)
+    calls = _spy_routes(monkeypatch, mod,
+                        (f"_{driver}_iter", f"_{driver}_rec"))
+    if driver == "potrf":
+        a = np.asarray(random_spd(n, dtype=jnp.float64, seed=17))
+        L, info = st.potrf(st.hermitian(np.tril(a), nb=nb,
+                                        uplo=Uplo.Lower))
+        np.testing.assert_allclose(np.tril(L.to_numpy()),
+                                   np.linalg.cholesky(a),
+                                   rtol=1e-10, atol=1e-10)
+    else:
+        a = _randn(n, n, np.float64)
+        LU, perm, info = st.getrf(st.from_dense(a, nb=nb))
+        _check_lu(a, LU, perm, nb)
+    assert int(info) == 0
+    if past_guard:
+        assert calls["rec"] >= 1 and calls["iter"] == 2
+    else:
+        assert calls["iter"] == 1 and calls["rec"] == 0
 
 
 def test_potrf_iter_matches_recursion_within_reassociation(monkeypatch):
     """The in-place iterative loop reassociates the trailing update
     (slab gemms vs the recursion's split gemms), so the two dispatches
     agree to factorization accuracy, not bitwise. Force the TRUE
-    recursion (crossover to 0 so its iterative base case never runs)
-    and compare."""
+    recursion (nt past the guard, crossover to 0 so its iterative base
+    case never runs) and compare."""
     n, nb = 128, 32
     a = np.asarray(random_spd(n, dtype=jnp.float64, seed=13))
     A = st.hermitian(np.tril(a), nb=nb, uplo=Uplo.Lower)
     L1, i1 = st.potrf(A)
+    monkeypatch.setattr(chol_mod, "_ITER_MAX_NT", 2)
     monkeypatch.setattr(chol_mod, "_POTRF_ITER_BASE", 0)
-    L0, i0 = st.potrf(A, Options(factor_iter_large=False))
+    L0, i0 = st.potrf(A)
     assert int(i1) == int(i0) == 0
     scale = np.linalg.norm(a, 1) * n * np.finfo(np.float64).eps
     assert np.abs(L1.to_numpy() - L0.to_numpy()).max() < 10 * scale
 
 
 def test_hlo_potrf_iter_updates_in_place_no_full_concat(monkeypatch):
-    """ISSUE 2(b) HLO guard: the default potrf outer loop updates the
-    trailing matrix via dynamic_update_slice and builds NO full-matrix
-    concatenation (the recursion's per-level copies). The legacy
-    recursion arm (crossover forced to 0 so its iterative base case
-    never runs) must show the full-size concatenate, proving the probe
-    detects it."""
+    """HLO guard: the iterative potrf outer loop updates the trailing
+    matrix via dynamic_update_slice and builds NO full-matrix
+    concatenation (the recursion's per-level copies). The recursion
+    (nt past the guard, crossover forced to 0 so its iterative base
+    case never runs) must show the full-size concatenate, proving the
+    probe detects it."""
     n, nb = 256, 32
     a = np.asarray(random_spd(n, dtype=jnp.float32, seed=3))
     A = st.hermitian(np.tril(a), nb=nb, uplo=Uplo.Lower)
@@ -271,9 +312,10 @@ def test_hlo_potrf_iter_updates_in_place_no_full_concat(monkeypatch):
     assert "stablehlo.dynamic_update_slice" in txt
     assert not cat.search(txt), \
         "default potrf still concatenates a full-size trailing copy"
+    monkeypatch.setattr(chol_mod, "_ITER_MAX_NT", 4)
     monkeypatch.setattr(chol_mod, "_POTRF_ITER_BASE", 0)
-    legacy = lower_text(Options(factor_iter_large=False))
-    assert cat.search(legacy), "probe lost its reference signal"
+    rec = lower_text(Options())
+    assert cat.search(rec), "probe lost its reference signal"
 
 
 def test_hlo_geqrf_updates_in_place():

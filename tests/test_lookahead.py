@@ -15,13 +15,11 @@ Covers:
     needs a backend whose scheduler actually reorders (skips on CPU,
     like test_distribution's async-collective test).
 
-(b) BATCHED CALU TOURNAMENT ROUNDS (Options.lu_tournament_batched,
-    default on) — each round is ONE batched panel LU
-    (blocked.panel_getrf_batched) instead of vmap(lax.linalg.lu)'s
+(b) BATCHED CALU TOURNAMENT ROUNDS — each round is ONE batched panel
+    LU (blocked.panel_getrf_batched) instead of vmap(lax.linalg.lu)'s
     sequential per-block custom-call loop. Guarded by a dispatch-policy
     spy and an HLO probe (no lapack getrf custom-call in the lowered
-    tournament; the legacy arm shows it — the probe's positive
-    control).
+    tournament).
 
 (c) MESH PERM CORRUPTION, ROOT-CAUSED (the CHANGES.md round-6 open
     item): two pre-0.6 SPMD partitioner mis-lowerings — the
@@ -313,9 +311,8 @@ def test_herk_trailing_inplace_split_equals_whole():
 # -- (b) batched CALU tournament rounds -------------------------------------
 
 def test_calu_batched_dispatch_policy(monkeypatch):
-    """Default CALU routes every tournament round through the batched
-    panel LU; the legacy arm routes none (and falls back to
-    vmap(lax.linalg.lu))."""
+    """CALU routes every tournament round through the batched panel
+    LU."""
     calls = {"batched": 0}
     orig = blocked.panel_getrf_batched
 
@@ -329,16 +326,11 @@ def test_calu_batched_dispatch_policy(monkeypatch):
     A = st.from_dense(a, nb=nb)
     st.getrf(A, Options(method_lu=MethodLU.CALU))
     assert calls["batched"] > 0, "batched rounds never consulted"
-    calls["batched"] = 0
-    st.getrf(A, Options(method_lu=MethodLU.CALU,
-                        lu_tournament_batched=False))
-    assert calls["batched"] == 0, "legacy arm leaked into batched rounds"
 
 
 def test_hlo_calu_rounds_have_no_lu_custom_call():
-    """ISSUE 3 acceptance: the lowered default CALU program contains
-    NO lax.linalg.lu custom-call (the per-block sequential loop); the
-    legacy arm shows it — the probe's positive control."""
+    """The lowered CALU program contains NO lax.linalg.lu custom-call
+    (the per-block sequential loop)."""
     n, nb = 128, 32
     a = _randn(n, n, np.float32)
     A = st.from_dense(a, nb=nb)
@@ -349,9 +341,6 @@ def test_hlo_calu_rounds_have_no_lu_custom_call():
         return jax.jit(f).lower(A).as_text()
 
     assert "getrf_ffi" not in lower_text(Options(method_lu=MethodLU.CALU))
-    assert "getrf_ffi" in lower_text(
-        Options(method_lu=MethodLU.CALU, lu_tournament_batched=False)), \
-        "probe lost its reference signal"
 
 
 def test_panel_getrf_batched_matches_sequential():

@@ -182,10 +182,11 @@ def test_getrf_pivot_threshold_tournament():
     assert np.abs(a @ X.to_numpy() - b).max() < n * 1e-12
 
 
-def test_getrf_pivot_threshold_recursive_base():
-    """Tall single-panel shape routes through _getrf_rec's tournament
-    base (the iterative path needs k % nb == 0 AND k//nb > 1)."""
+def test_getrf_pivot_threshold_recursive_base(monkeypatch):
+    """Tall single-panel shape through _getrf_rec's tournament base
+    (reached with the iterative loop's nt guard below the shape's)."""
     from slate_tpu.core.types import Options
+    monkeypatch.setattr(lu_mod, "_ITER_MAX_NT", 0)
     m, n, nb = 160, 32, 32
     rng = np.random.default_rng(11)
     a = rng.standard_normal((m, n))
@@ -200,14 +201,12 @@ def test_getrf_pivot_threshold_recursive_base():
 
 
 def test_getrf_rec_iter_base_dispatch(monkeypatch):
-    """Round-5 hybrid dispatch — now the LEGACY arm
-    (Options(factor_iter_large=False); the round-6 default routes every
-    nt ≤ 64 width straight to the pivot-fused iterative loop): the
-    width recursion above the iter crossover, the flat iterative loop
-    as its base case. With the crossover lowered to 64, n=128 must
-    split once in _getrf_rec and factor each 64-wide half with
-    _getrf_iter. Verifies the residual AND the solve built on it."""
-    monkeypatch.setattr(lu_mod, "_GETRF_ITER_BASE", 64)
+    """The hybrid dispatch past the HLO-size guard: the width recursion
+    above it, the flat iterative loop as its base case. With the guard
+    lowered to nt = 4, n=128 at nb=16 (nt = 8) must split once in
+    _getrf_rec and factor each 64-wide half with _getrf_iter. Verifies
+    the residual AND the solve built on it."""
+    monkeypatch.setattr(lu_mod, "_ITER_MAX_NT", 4)
     calls = {"iter": 0, "rec": 0}
     for name in ("_getrf_iter", "_getrf_rec"):
         orig = getattr(lu_mod, name)
@@ -219,10 +218,10 @@ def test_getrf_rec_iter_base_dispatch(monkeypatch):
 
         monkeypatch.setattr(lu_mod, name, spy)
 
-    n, nb = 128, 16  # 128 > 64 -> rec splits; halves 64 <= 64 -> iter
+    n, nb = 128, 16  # nt 8 > 4 -> rec splits; halves nt 4 -> iter
     a = RNG.standard_normal((n, n))
     A = st.from_dense(a, nb=nb)
-    LU, perm, info = lu_mod.getrf(A, Options(factor_iter_large=False))
+    LU, perm, info = lu_mod.getrf(A)
     assert int(info) == 0
     assert calls["rec"] >= 1 and calls["iter"] == 2
     lu = np.asarray(LU.dense_canonical())
@@ -242,16 +241,15 @@ def test_getrf_rec_iter_base_dispatch(monkeypatch):
 @pytest.mark.slow  # ~10 s (round-10 headroom); threshold/tournament
 # pivoting stays pinned by test_getrf_pivot_threshold_tournament
 def test_getrf_rec_tournament_threshold(monkeypatch):
-    """pivot_threshold < 1 with the crossover lowered: the recursion's
+    """pivot_threshold < 1 past the lowered nt guard: the recursion's
     full-gather permutation composition (threshold < 1 path) composes
     with _getrf_iter's tournament (compaction-perm) panels — pin that
     composition stays correct."""
-    monkeypatch.setattr(lu_mod, "_GETRF_ITER_BASE", 64)
+    monkeypatch.setattr(lu_mod, "_ITER_MAX_NT", 4)
     n, nb = 128, 16
     a = RNG.standard_normal((n, n))
     A = st.from_dense(a, nb=nb)
-    LU, perm, info = lu_mod.getrf(
-        A, Options(pivot_threshold=0.5, factor_iter_large=False))
+    LU, perm, info = lu_mod.getrf(A, Options(pivot_threshold=0.5))
     assert int(info) == 0
     lu = np.asarray(LU.dense_canonical())
     l = np.tril(lu, -1) + np.eye(len(perm))

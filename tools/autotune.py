@@ -8,9 +8,8 @@ the small batched engine — per (op, pow2-n-bucket, dtype, platform).
 Every candidate is AOT-compiled ONCE (compiles are counted into the
 artifact — the search's own cost is part of the record), then
 slope-timed: seconds(k2 iters) − seconds(k1 iters) over (k2 − k1)
-cancels the per-call dispatch constant, the same measurement
-discipline bench.py --phases uses. The score joins the measured slope
-against the roofline cost model (obs/costs.py ``score_measured`` →
+cancels the per-call dispatch constant. The score joins the measured
+slope against the roofline cost model (obs/costs.py ``score_measured`` →
 model-flops GFLOP/s, intensity, roof fraction) and the argmax-GFLOP/s
 candidate per (op, n-bucket, dtype) becomes one table entry.
 
