@@ -625,8 +625,11 @@ def permute_rows_limited(x: Array, perm: Array, max_moved: int) -> Array:
     The iterative getrf/getrf_tntpiv loops never call this per level
     — the permutation is folded into the trailing update's row reads
     (pivot fusion, linalg/lu.py) and the stored L columns are
-    reordered once at the end. This materialized permute remains in
-    the recursion (_getrf_rec) and the wide-matrix rest solve."""
+    reordered once at the end. _getrf_iter gathers those rows whole
+    from the trailing block it carries as an array of its own, never
+    from a copied column range of the matrix. This materialized
+    permute remains in the recursion (_getrf_rec) and the wide-matrix
+    rest solve."""
     del max_moved
     with jax.named_scope("row_swap"):
         return x[perm]

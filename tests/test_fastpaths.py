@@ -168,12 +168,15 @@ _GATHER_RE = re.compile(
     r'stablehlo\.gather.*->\s*tensor<(\d+)x(\d+)x(f32|f64)>')
 
 
-def _fullwidth_gather_count(opts, n=192, nb=64):
-    """Count 2-D gathers in the LOWERED getrf program whose result is a
-    FULL-width (npad-column) row block — the materialized permuted copy
-    the fused path must never create. Lowered (pre-fusion) StableHLO is
-    the right level: the property is structural, not an artifact of the
-    backend's fusion decisions."""
+N_FW, NB_FW = 192, 64
+
+
+def _fullwidth_gather_rows(opts, n=N_FW, nb=NB_FW):
+    """Row counts of the 2-D gathers in the LOWERED getrf program whose
+    result is a FULL-width (npad-column) row block — a materialized
+    permuted copy that carries the stored L columns along. Lowered
+    (pre-fusion) StableHLO is the right level: the property is
+    structural, not an artifact of the backend's fusion decisions."""
     a = RNG.standard_normal((n, n)).astype(np.float32)
     A = st.from_dense(a, nb=nb)
 
@@ -181,21 +184,24 @@ def _fullwidth_gather_count(opts, n=192, nb=64):
         return st.getrf(A, opts)[0].data
 
     txt = jax.jit(f).lower(A).as_text()
-    widths = [int(m.group(2)) for m in _GATHER_RE.finditer(txt)]
-    return sum(1 for w in widths if w == n)
+    shapes = [(int(m.group(1)), int(m.group(2)))
+              for m in _GATHER_RE.finditer(txt)]
+    return sorted(rows for rows, w in shapes if w == n)
 
 
 def test_hlo_getrf_fused_has_no_fullwidth_permuted_copy():
     """The traffic assertion of pivot fusion: getrf's per-level
     trailing update contains NO materialized full-width permuted row
-    block — its gathers are the nb-row pivot read, the (n−k1)-wide
-    trailing read fused into the Schur subtract, and the nb-wide
-    deferred-left-swap blocks."""
-    assert _fullwidth_gather_count(Options()) == 0
+    block. Each level gathers whole rows of its trailing block (the nb
+    pivot rows, then the rows below them), so only the first level,
+    whose trailing block is the input with its panel's columns, reads
+    full-width rows; later levels gather the (n−k0)- or (n−k1)-wide
+    trailing block, and the deferred left swaps gather nb-wide blocks."""
+    assert _fullwidth_gather_rows(Options()) == [NB_FW, N_FW - NB_FW]
 
 
 def test_hlo_getrf_tntpiv_fused_has_no_fullwidth_permuted_copy():
-    assert _fullwidth_gather_count(Options(method_lu=MethodLU.CALU)) == 0
+    assert _fullwidth_gather_rows(Options(method_lu=MethodLU.CALU)) == []
 
 
 # -- (b) in-place iterative outer loops -------------------------------------
